@@ -38,6 +38,7 @@ from repro.core.link import (PAPER_TIMING, SERIAL_LVDS_TIMING,
                              per_link_timing)
 from repro.core.router import (AddressSpec, MulticastTable, mesh2d_topology,
                                ring_topology)
+from repro.runtime.compile_cache import enable_compile_cache
 
 EVENTS_PER_CHIP = 48
 SWEEP_N = (2, 4, 8, 16)
@@ -657,23 +658,6 @@ def sweep_verify(engine=DEFAULT_ENGINE, slow=False):
                   api="fabric.verify", tags=("verify",))]
 
 
-def enable_persistent_compile_cache():
-    """Opt this process into a persistent XLA compile cache so repeat
-    sweep runs (and CI with a cache action) skip the one shared engine
-    compilation.  Called from sweep entry points only — importing this
-    module must not mutate global JAX config, which would silently
-    change what other benchmarks measure."""
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                           os.path.join(os.path.dirname(__file__),
-                                        ".jax_cache")))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # pragma: no cover - older jax without the knobs
-        pass
-
-
 #: Every cell tag a sweep family can emit — the single source of truth
 #: the CLIs validate ``--tags`` against.
 KNOWN_TAGS = frozenset({"hetero", "mcast", "adaptive", "lossless",
@@ -689,7 +673,7 @@ def run_structured(engine=DEFAULT_ENGINE, slow=False, tags=None):
     included).  Unknown tags raise — a typo must not produce an empty
     benchmark run that looks successful.
     """
-    enable_persistent_compile_cache()
+    enable_compile_cache()
     wanted = frozenset(tags) if tags else None
     families = (
         (sweep_anchor, (engine,), frozenset()),

@@ -34,9 +34,27 @@ import json
 import os
 import sys
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # B/s
-LINK_BW = 50e9           # B/s per direction per link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect over 4 links).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_s": 819e9,
+                    "link_bytes_s": 50e9},
+}
+
+#: The part the dry-run artifacts were compiled for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip; a device that is not in :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                           "dryrun")
@@ -104,15 +122,16 @@ def _ssm_state_flops_per_token(arch: str) -> float:
     return 9.0 * 3.0 * n_mamba * d_in * cfg.mamba.d_state
 
 
-def analyze_cell(rec: dict) -> dict:
+def analyze_cell(rec: dict, device_kind: str = DRYRUN_DEVICE_KIND) -> dict:
+    pk = peaks(device_kind)
     n_dev = rec["n_devices"]
     flops_dev = rec["flops"]
     bytes_dev = rec["bytes_accessed"]
     coll_dev = rec.get("collective_bytes_total", 0.0)
 
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / LINK_BW
+    t_compute = flops_dev / pk["flops"]
+    t_memory = bytes_dev / pk["hbm_bytes_s"]
+    t_coll = coll_dev / pk["link_bytes_s"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -197,18 +216,14 @@ def fabric_roofline_cells() -> list:
       words one micro-transaction round-trips;
     * ``bytes_per_event`` — carry read+write per launch group, times
       launch groups per run, over delivered events;
-    * ``bound_ev_s``      — ``HBM_BW / bytes_per_event``, the roofline
-      ceiling for this shape on the modeled part;
+    * ``bound_ev_s``      — HBM bandwidth / ``bytes_per_event``, the
+      roofline ceiling for this shape on the device the run is on
+      (:data:`PEAKS`; an unknown device raises);
     * ``measured_ev_s``   — delivered events over wall-clock, and the
       fraction of the bound it reaches.
 
-    On this CPU interpret-mode container the measured fraction is tiny
-    (interpret mode executes the kernel body as jnp ops — it measures
-    semantics, not deployment speed); the cells exist so a compiled
-    backend (TPU/GPU) reports its fraction against the SAME bound, and
-    so the multi-step kernel's ``chunk``-fold bytes/event reduction is
-    visible in the artifact.  Every cell carries ``backend`` +
-    ``kernel`` fields; ``compare.py`` only gates same-backend ratios.
+    Every cell carries ``backend`` + ``kernel`` fields; ``compare.py``
+    only gates same-backend ratios.
     """
     import time
 
@@ -221,6 +236,7 @@ def fabric_roofline_cells() -> list:
     from repro.core.network import slot_carry_bytes
     from repro.core.router import ring_topology
 
+    hbm_bytes_s = peaks(jax.devices()[0].device_kind)["hbm_bytes_s"]
     topo = ring_topology(16)
     spec = tr.hot_spot(jax.random.PRNGKey(5), 16, 3, mean_gap_ns=150.0,
                        hot_frac=0.75)
@@ -240,7 +256,7 @@ def fabric_roofline_cells() -> list:
         bytes_per_step = 2.0 * carry_bytes / steps_per_launch
         delivered = max(int(res.delivered), 1)
         bytes_per_event = bytes_per_step * max_steps / delivered
-        bound_ev_s = HBM_BW / bytes_per_event
+        bound_ev_s = hbm_bytes_s / bytes_per_event
         measured_ev_s = delivered / (us * 1e-6)
         m = _metrics(res)
         m.update({"carry_bytes": carry_bytes,

@@ -12,6 +12,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main(argv=None) -> None:
+    import jax
+
     from benchmarks import fabric_sweep, paper_benches, roofline
     from repro.core.network import ENGINES
     p = argparse.ArgumentParser()
@@ -49,10 +51,10 @@ def main(argv=None) -> None:
                                                    tags=tag_sel)
     except ValueError as e:   # unknown --tags: fail loudly, not empty
         p.error(str(e))
-    if tag_sel is None:
-        # the fabric roofline cells (both pallas kernels vs the
-        # memory-bandwidth bound) ride every untagged fabric sweep —
-        # they are the per-backend MEv/s-vs-roofline artifact rows
+    if tag_sel is None and jax.default_backend() == "tpu":
+        # the fabric roofline cells (both pallas kernels vs the chip's
+        # memory-bandwidth bound) ride every untagged fabric sweep on a
+        # TPU; elsewhere there is no chip bound to compare against
         fabric_cells.extend(roofline.fabric_roofline_cells())
     if args.only not in (None, "fabric"):
         all_names = [c["name"] for c in fabric_cells]
@@ -71,7 +73,6 @@ def main(argv=None) -> None:
         print(f"{name},{us:.1f},{derived}")
 
     if args.json:
-        import jax
         import jaxlib
         with open(args.json, "w") as f:
             json.dump({"bench": "fabric_sweep", "engine": args.engine,

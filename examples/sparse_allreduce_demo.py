@@ -15,6 +15,7 @@ import sys
 if "XLA_FLAGS" not in os.environ:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"    # forced host devices: never the chip
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     sys.exit(subprocess.call([sys.executable, __file__], env=env))
 

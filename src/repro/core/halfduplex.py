@@ -25,8 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..parallel.compat import axis_size
-
 
 def _ring_perm(n, reverse=False):
     if reverse:
@@ -48,7 +46,7 @@ def ring_reduce_scatter(x, axis_name, *, reverse=False):
     x: identical-shape local array per device. Returns this device's
     reduced chunk (flattened, 1/n of padded x).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     flat, _ = _pad_to(x, n)
     chunks = flat.reshape(n, -1)
@@ -70,7 +68,7 @@ def ring_reduce_scatter(x, axis_name, *, reverse=False):
 
 def ring_all_gather(x, axis_name, *, reverse=False):
     """Unidirectional ring all-gather: local chunk -> (n * chunk) flat."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = _ring_perm(n, reverse)
     sign = -1 if reverse else 1
@@ -95,7 +93,7 @@ def ring_allreduce(x, axis_name, *, bidirectional=False):
     both ICI link directions utilized (the paper-adapted schedule).
     """
     shape, dtype = x.shape, x.dtype
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if not bidirectional:

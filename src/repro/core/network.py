@@ -116,8 +116,8 @@ engines (select with ``engine=``):
     ``lax.while_loop`` and exits within one chunk of
     ``delivered + drops == injected`` instead of padding to
     ``max_steps``, and the whole simulation is compiled once per shape
-    signature through a jit cache with buffer donation (stream widths
-    are bucketed to powers of two so sweep cells share compilations).
+    signature through a jit cache (stream widths are bucketed to powers
+    of two so sweep cells share compilations).
 
 ``"reference"``
     The flat one-shot slot-array engine (PR 1): every step re-scans all
@@ -178,6 +178,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.indexing import XLA_INDEX
 from .link import LinkTiming, PAPER_TIMING
 from .protocol_sim import BIG_NS, LinkState, link_step_batch, reset_link
 from .transceiver import XcvrState
@@ -668,14 +669,6 @@ def _overflow_guard_routed(t_max: int, link_tx: np.ndarray,
             f"split the simulation.")
 
 
-def _jit_cached(fn, donate_argnums=()):
-    """jit with buffer donation where the backend supports it (donation
-    on CPU is a no-op warning in current JAX, so skip it there)."""
-    if donate_argnums and jax.default_backend() != "cpu":
-        return jax.jit(fn, donate_argnums=donate_argnums)
-    return jax.jit(fn)
-
-
 # -----------------------------------------------------------------------
 # Per-step pieces shared verbatim by every engine body (the bit-exactness
 # contract lives here: one implementation of delivery logging and of the
@@ -683,17 +676,19 @@ def _jit_cached(fn, donate_argnums=()):
 # -----------------------------------------------------------------------
 
 def _log_deliveries(log_inj, log_del, log_dest, log_n,
-                    deliver, ev_inj, t_del, ev_dest, n_slots: int):
+                    deliver, ev_inj, t_del, ev_dest, n_slots: int,
+                    ix=XLA_INDEX):
     """Append this step's deliveries to the packed log (order: link id)."""
     d32 = deliver.astype(jnp.int32)
-    slot = jnp.where(deliver, log_n + jnp.cumsum(d32) - d32, n_slots)
-    return (log_inj.at[slot].set(ev_inj, mode="drop"),
-            log_del.at[slot].set(t_del, mode="drop"),
-            log_dest.at[slot].set(ev_dest, mode="drop"),
+    slot = jnp.where(deliver, log_n + ix.cumsum(d32) - d32, n_slots)
+    return (ix.set(log_inj, slot, ev_inj),
+            ix.set(log_del, slot, t_del),
+            ix.set(log_dest, slot, ev_dest),
             log_n + jnp.sum(d32))
 
 
-def _forward_slots(forward, fq, n_ins_flat, cap, n_queues: int):
+def _forward_slots(forward, fq, n_ins_flat, cap, n_queues: int,
+                   ix=XLA_INDEX):
     """Insertion slots for this step's forward copies.
 
     ``forward`` / ``fq`` are flat (M,) candidate arrays in priority
@@ -708,31 +703,39 @@ def _forward_slots(forward, fq, n_ins_flat, cap, n_queues: int):
     """
     idx = jnp.arange(forward.shape[0])
     fq_m = jnp.where(forward, fq, n_queues)   # sentinel for non-forwards
+    # (int32 before the broadcast: Mosaic cannot reshape bool vectors)
     before = (fq_m[None, :] == fq_m[:, None]) \
-        & (idx[None, :] < idx[:, None]) & forward[None, :]
+        & (idx[None, :] < idx[:, None]) \
+        & (forward.astype(jnp.int32)[None, :] > 0)
     offs = jnp.sum(before.astype(jnp.int32), axis=1)
     fq_g = jnp.where(forward, fq, 0)
-    key = n_ins_flat[fq_g] + offs             # next free slot
+    key = ix.take(n_ins_flat, fq_g) + offs    # next free slot
     cap_ok = key < cap
     app = forward & cap_ok
     return fq_g, key, app, forward & ~cap_ok
 
 
-def _replicate(route_out_j, route_wt_j, rx_chip, ev_route, did):
+def _replicate(route_out_j, route_wt_j, rx_chip, ev_route, did,
+               ix=XLA_INDEX):
     """Gather one step's forward copies from the replication tables.
 
     Returns flat (L·K,) ``(forward mask, queue id, drop weight)`` in the
     link-major / replica-minor priority order ``_forward_slots``
     expects.  With unicast-only tables (K = 1) this is exactly the
     historical single next-hop gather."""
-    out_qk = route_out_j[rx_chip, ev_route]              # (L, K)
-    wt_k = route_wt_j[rx_chip, ev_route]                 # (L, K)
-    fwd = (did[:, None] & (out_qk >= 0)).reshape(-1)
-    return fwd, jnp.maximum(out_qk, 0).reshape(-1), wt_k.reshape(-1)
+    K = route_out_j.shape[-1]
+    out_qk = ix.flat(jnp.stack(
+        [ix.take_nr(route_out_j, rx_chip, ev_route, k) for k in range(K)],
+        axis=1))                                         # (L·K,)
+    wt_k = ix.flat(jnp.stack(
+        [ix.take_nr(route_wt_j, rx_chip, ev_route, k) for k in range(K)],
+        axis=1))
+    fwd = (ix.repeat(did.astype(jnp.int32), K) > 0) & (out_qk >= 0)
+    return fwd, jnp.maximum(out_qk, 0), wt_k
 
 
 def _flow_gate(fc_mode, cap, xon, occ, xoff, cand_route, rx_chip_cand,
-               route_out_j):
+               route_out_j, ix=XLA_INDEX):
     """Flow-control admission gate for one micro-transaction.
 
     For every endpoint queue, looks up the downstream queues its head
@@ -756,15 +759,15 @@ def _flow_gate(fc_mode, cap, xon, occ, xoff, cand_route, rx_chip_cand,
     """
     xoff2 = jnp.where(occ >= cap, jnp.int32(1),
                       jnp.where(occ <= xon, jnp.int32(0), xoff))
-    tgt = route_out_j[rx_chip_cand, cand_route]          # (L, 2, K)
-    real = tgt >= 0
-    tgt_g = jnp.maximum(tgt, 0)
-    occ_t = occ.reshape(-1)[tgt_g]
-    xoff_t = xoff2.reshape(-1)[tgt_g]
-    full = jnp.any(real & (occ_t >= cap), axis=2)
-    off = jnp.any(real & (xoff_t > 0), axis=2)
-    blocked = jnp.where(fc_mode == 1, full,
-                        jnp.where(fc_mode == 2, off, False))
+    occ_f, xoff_f = ix.flat(occ), ix.flat(xoff2)
+    full = off = jnp.zeros(occ.shape, bool)
+    for k in range(route_out_j.shape[-1]):
+        tgt = ix.take_nr(route_out_j, rx_chip_cand, cand_route, k)  # (L, 2)
+        real = tgt >= 0
+        tgt_g = jnp.maximum(tgt, 0)
+        full = full | (real & (ix.take(occ_f, tgt_g) >= cap))
+        off = off | (real & (ix.take(xoff_f, tgt_g) > 0))
+    blocked = ((fc_mode == 1) & full) | ((fc_mode == 2) & off)
     return blocked, xoff2
 
 
@@ -836,7 +839,8 @@ def _slot_results(final: _SlotState):
 def _slot_step_body(L: int, E: int, C: int, max_burst: int,
                     scan_fn, update_fn,
                     links_j, route_out_j, route_del_j, route_wt_j,
-                    t_cycle_v, t_rev_v, t_idle_v, cap, fc_mode, xon):
+                    t_cycle_v, t_rev_v, t_idle_v, cap, fc_mode, xon,
+                    ix=XLA_INDEX):
     """Build the per-micro-transaction physics ``body(s, step_i) -> s'``.
 
     ONE implementation of the slot-engine step, closed over the dynamic
@@ -846,10 +850,14 @@ def _slot_step_body(L: int, E: int, C: int, max_burst: int,
     kernel body / its oracle (= the value-level kernel math) — which is
     what makes ``kernel="multistep"`` bit-exact by construction rather
     than by parallel maintenance.
+
+    Every gather and scatter goes through ``ix``
+    (:mod:`repro.kernels.indexing`): plain indexing in XLA, one-hot
+    reductions inside the multi-step kernel, where the replication
+    tables arrive in ``ix.table`` layout.
     """
     Q = 2 * L
-    lidx = jnp.arange(L)
-    K = route_out_j.shape[2]
+    K = route_out_j.shape[-1]
     # the chip a pop over (link, side) would deliver into — the gate
     # needs it for both sides before the FSM picks a direction
     rx_chip_cand = jnp.stack([links_j[:, 1], links_j[:, 0]], axis=1)
@@ -864,14 +872,14 @@ def _slot_step_body(L: int, E: int, C: int, max_burst: int,
             # lowest slot, i.e. FIFO among simultaneous arrivals), which
             # for the sorted single-hop prefill is exactly simulate()'s
             # searchsorted count.
-            t_q = jnp.repeat(t_now, 2)                           # (Q,)
+            t_q = ix.repeat(t_now, 2)                            # (Q,)
             pend_q, r_min_q, nxt_q, amin_q, busy_q, route_q = scan_fn(
                 s.q_time, s.q_dest, t_q)
-            pend = pend_q.reshape(L, 2)
+            pend = ix.unflat(pend_q, 2)
             # telemetry: backlog-present integral per endpoint queue
-            busy_steps = s.busy_steps + busy_q.reshape(L, 2)
-            r_min = r_min_q.reshape(L, 2)
-            nxt2 = nxt_q.reshape(L, 2)                           # (L, 2)
+            busy_steps = s.busy_steps + ix.unflat(busy_q, 2)
+            r_min = ix.unflat(r_min_q, 2)
+            nxt2 = ix.unflat(nxt_q, 2)                           # (L, 2)
 
             # --- flow-control admission gate ----------------------------
             # Would this queue's head pop into a backpressured queue?
@@ -879,10 +887,10 @@ def _slot_step_body(L: int, E: int, C: int, max_burst: int,
             # no pending work (the event stays in its slot, the link
             # idles — the 4-phase "receiver withholds ack" behaviour).
             occ = s.n_ins - s.n_pop
-            cand_route = route_q.reshape(L, 2)
+            cand_route = ix.unflat(route_q, 2)
             blocked, xoff = _flow_gate(fc_mode, cap, xon, occ, s.xoff,
                                        cand_route, rx_chip_cand,
-                                       route_out_j)
+                                       route_out_j, ix)
             stalled = (pend > 0) & blocked
             stall_steps = s.stall_steps + stalled.astype(jnp.int32)
             credit_waits = s.credit_waits + (
@@ -953,49 +961,54 @@ def _slot_step_body(L: int, E: int, C: int, max_burst: int,
             # the transmission cost, so the gated delta is bus-busy time
             busy_ns = s.busy_ns + jnp.where(did, link.t - t_now, 0)
             send_side = jnp.where(out.tx_l == 1, 0, 1)           # (L,)
-            qid = lidx * 2 + send_side                           # (L,)
-            pop_slot = amin_q[qid]
-            ev_route = cand_route[lidx, send_side]  # == q_dest[qid, slot]
-            ev_inj = s.q_inj[qid, pop_slot]
+            qid = jax.lax.broadcasted_iota(jnp.int32, (L,), 0) * 2 \
+                + send_side                                      # (L,)
+            pop_slot = ix.take(amin_q, qid)
+            # == q_dest[qid, pop_slot] / q_inj[qid, pop_slot]
+            ev_route = jnp.where(send_side == 0, cand_route[:, 0],
+                                 cand_route[:, 1])
+            ev_inj = ix.take(ix.pick(s.q_inj, amin_q), qid)
             # consume the popped slot (one-shot slots; no reuse) and
             # return its credit (occupancy = n_ins - n_pop drops by one)
             pop_q = jnp.where(did, qid, Q)
-            sent = s.sent.at[lidx, send_side].add(did32)
-            n_pop = s.n_pop.at[lidx, send_side].add(did32)
+            sent_now = jnp.stack([1 - send_side, send_side],
+                                 axis=1) * did32[:, None]
+            sent = s.sent + sent_now
+            n_pop = s.n_pop + sent_now
 
             # --- deliver and/or replicate -------------------------------
             # The receiving chip's replication-table row decides both: a
             # branch node of a multicast tree can deliver locally AND
             # spawn several child copies from this one pop.
             rx_chip = jnp.where(out.tx_l == 1, links_j[:, 1], links_j[:, 0])
-            deliver = did & (route_del_j[rx_chip, ev_route] > 0)
+            deliver = did & (ix.take_nr(route_del_j, rx_chip, ev_route) > 0)
 
             log_inj, log_del, log_dest, log_n = _log_deliveries(
                 s.log_inj, s.log_del, s.log_dest, s.log_n,
-                deliver, ev_inj, link.t, rx_chip, E)
+                deliver, ev_inj, link.t, rx_chip, E, ix)
 
             fwd_f, fqk_f, wt_f = _replicate(route_out_j, route_wt_j,
-                                            rx_chip, ev_route, did)
-            n_ins_f = s.n_ins.reshape(-1)
+                                            rx_chip, ev_route, did, ix)
+            n_ins_f = ix.flat(s.n_ins)
             # drop mode enforces the logical budget at append time (the
             # historical one-shot total-through bound); the stall modes
             # never discard — physical width C always fits (cap == C in
             # the unbounded default, so this is bit-exactly PR 5 there)
             app_cap = jnp.where(fc_mode == 0, jnp.minimum(cap, C), C)
             fq_g, slot, app, dropped = _forward_slots(
-                fwd_f, fqk_f, n_ins_f, app_cap, Q)
+                fwd_f, fqk_f, n_ins_f, app_cap, Q, ix)
             fq_s = jnp.where(app, fq_g, Q)         # drop non-appends
             q_time, q_dest, q_inj = update_fn(
                 s.q_time, s.q_dest, s.q_inj, pop_q, pop_slot,
-                fq_s, slot, jnp.repeat(link.t, K),
-                jnp.repeat(ev_route, K), jnp.repeat(ev_inj, K))
-            n_ins = n_ins_f.at[fq_s].add(1, mode="drop").reshape(L, 2)
+                fq_s, slot, ix.repeat(link.t, K),
+                ix.repeat(ev_route, K), ix.repeat(ev_inj, K))
+            n_ins = ix.unflat(ix.add(n_ins_f, fq_s, jnp.ones_like(fq_s)), 2)
             drop_wt = jnp.where(dropped, wt_f, 0)
             drops = s.drops + jnp.sum(drop_wt)
             # telemetry: charge each weighted drop to its target queue
-            q_drops = s.q_drops.reshape(-1).at[
-                jnp.where(dropped, fq_g, Q)].add(
-                drop_wt, mode="drop").reshape(L, 2)
+            q_drops = ix.unflat(ix.add(ix.flat(s.q_drops),
+                                       jnp.where(dropped, fq_g, Q),
+                                       drop_wt), 2)
 
             # --- switch counting (matches SimResult.n_switches: mode_l
             # transitions between consecutive steps, reset excluded) -----
@@ -1137,6 +1150,17 @@ def slot_carry_bytes(L: int, E: int, C: int) -> int:
     return 4 * words
 
 
+def slot_step_temp_bytes(L: int, E: int, C: int, n_route_rows: int,
+                         K: int) -> int:
+    """Bytes of the largest one-hot temporaries of one slot step in its
+    :class:`~repro.kernels.indexing.OneHotIndex` form, with ``M = L·K``
+    forward copies: the delivery-log scatters (L, E), the forward-slot
+    ranking (M, M), the replication-table gathers (L, N·R) and the
+    append one-hots (M, C), int32 each."""
+    M = L * K
+    return 4 * (L * E + M * M + L * n_route_rows + M * C)
+
+
 def _slot_run_multistep(L: int, E: int, C: int, max_steps: int,
                         max_burst: int, chunk: int):
     """Multi-step variant of :func:`_slot_run`: same operand contract,
@@ -1148,20 +1172,26 @@ def _slot_run_multistep(L: int, E: int, C: int, max_steps: int,
     kernel body is the value-level scatter-as-matmul math
     (``scan_math`` / ``update_math``) — the same tile code the per-step
     kernels execute, now fused with the FSM/flow physics of
-    :func:`_slot_step_body`.
+    :func:`_slot_step_body`, whose gathers and scatters run in their
+    one-hot form (:class:`repro.kernels.indexing.OneHotIndex`).
 
     The final chunk's in-kernel loop bound is
     ``min(chunk, max_steps - base)``, so a binding ``max_steps`` is
     honoured exactly (post-bound steps never execute — they are not
     no-ops in general)."""
     from ..kernels import fabric_queue as fqk
+    from ..kernels.indexing import OneHotIndex
 
     def run(q_time, q_dest, q_inj, sizes, init_tx,
             links_j, route_out_j, route_del_j, route_wt_j,
             t_cycle_v, t_rev_v, t_idle_v, cap, fc_mode, xon):
         init = _slot_init(L, E, q_time, q_dest, q_inj, sizes, init_tx)
         carry0 = _pack_slot_state(init)
-        consts = (links_j, route_out_j, route_del_j, route_wt_j,
+        ix = OneHotIndex(route_out_j.shape[1])
+        N, R, K = route_out_j.shape[-3:]
+        temp_bytes = slot_step_temp_bytes(L, E, C, N * R, K)
+        consts = (links_j, ix.table(route_out_j), ix.table(route_del_j),
+                  ix.table(route_wt_j),
                   jnp.stack([t_cycle_v, t_rev_v, t_idle_v]),
                   jnp.stack([jnp.asarray(cap, jnp.int32),
                              jnp.asarray(fc_mode, jnp.int32),
@@ -1173,7 +1203,7 @@ def _slot_run_multistep(L: int, E: int, C: int, max_steps: int,
                 L, E, C, max_burst, fqk.scan_math, fqk.update_math,
                 links_c, rout_c, rdel_c, rwt_c,
                 timing_c[0], timing_c[1], timing_c[2],
-                par_c[0], par_c[1], par_c[2])
+                par_c[0], par_c[1], par_c[2], ix)
             return _pack_slot_state(body(_unpack_slot_state(car), step_i))
 
         # base rides an array derived from a batched operand (sizes) so
@@ -1187,7 +1217,8 @@ def _slot_run_multistep(L: int, E: int, C: int, max_steps: int,
             car, b = state
             out = fqk.fabric_queue_multistep_pallas(
                 car, consts, b, step_fn=step_fn,
-                chunk=chunk, max_steps=max_steps)
+                chunk=chunk, max_steps=max_steps,
+                step_temp_bytes=temp_bytes)
             return (tuple(out), b + chunk), None
 
         carry = carry0
@@ -1205,9 +1236,8 @@ def _slot_engine_multistep(L: int, E: int, C: int, max_steps: int,
     """Compile-once multi-step slot engine (``engine="pallas"`` with
     ``kernel="multistep"``): ceil(max_steps / chunk) fused kernel
     launches per run instead of 2·max_steps."""
-    return _jit_cached(
-        _slot_run_multistep(L, E, C, max_steps, max_burst, chunk),
-        donate_argnums=(0, 1, 2))
+    return jax.jit(
+        _slot_run_multistep(L, E, C, max_steps, max_burst, chunk))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1219,7 +1249,7 @@ def _slot_engine_multistep_batch(L: int, E: int, C: int, max_steps: int,
     rule (B independent carries per launch, interpret mode included)."""
     fn = jax.vmap(_slot_run_multistep(L, E, C, max_steps, max_burst,
                                       chunk))
-    return _jit_cached(_shard_over_batch(fn, n_devices))
+    return jax.jit(_shard_over_batch(fn, n_devices))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1232,15 +1262,15 @@ def _slot_engine(L: int, E: int, C: int, max_steps: int,
     contract, routing table and flow-control setting that fits the shape
     signature — see :func:`_slot_run` for the full operand contract.
     """
-    return _jit_cached(_slot_run(L, E, C, max_steps, max_burst,
-                                 use_kernels), donate_argnums=(0, 1, 2))
+    return jax.jit(_slot_run(L, E, C, max_steps, max_burst, use_kernels))
 
 
 def _shard_over_batch(fn, n_devices: int, n_args: int | None = None,
                       replicated: tuple = ()):
     """Split a batched engine's leading ``(B,)`` instance axis across
-    devices via ``shard_map`` (through :mod:`repro.parallel.compat`, so
-    old and new jax spellings both work).  Every operand and output
+    devices via ``jax.shard_map`` over a one-axis ``batch`` mesh (an Auto
+    axis, so the engine's indexing needs no sharding annotations).  Every
+    operand and output
     carries the batch axis leading, so one ``PartitionSpec("batch")``
     covers the whole tree — except the positional args named in
     ``replicated`` (with ``n_args`` total), which are shared scalars
@@ -1251,16 +1281,16 @@ def _shard_over_batch(fn, n_devices: int, n_args: int | None = None,
     instance globally).  ``n_devices <= 1`` is the identity."""
     if n_devices <= 1:
         return fn
-    from jax.sharding import PartitionSpec
+    from jax.sharding import AxisType, PartitionSpec
 
-    from ..parallel import compat
-    mesh = compat.make_mesh((int(n_devices),), ("batch",))
+    mesh = jax.make_mesh((int(n_devices),), ("batch",),
+                         axis_types=(AxisType.Auto,))
     spec = PartitionSpec("batch")
     in_specs = (spec if not replicated else
                 tuple(PartitionSpec() if i in replicated else spec
                       for i in range(n_args)))
-    return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=spec, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1281,7 +1311,7 @@ def _slot_engine_batch(L: int, E: int, C: int, max_steps: int,
     ``n_devices > 1`` the batch axis is additionally sharded across
     devices (see :func:`_shard_over_batch`)."""
     fn = jax.vmap(_slot_run(L, E, C, max_steps, max_burst, use_kernels))
-    return _jit_cached(_shard_over_batch(fn, n_devices))
+    return jax.jit(_shard_over_batch(fn, n_devices))
 
 
 # -----------------------------------------------------------------------
@@ -1293,7 +1323,7 @@ class _RingState(NamedTuple):
     h0: jnp.ndarray           # (L, 2) prefill head (also the pop tie key)
     fh: jnp.ndarray           # (L, 2, D) forward-stream heads
     ftl: jnp.ndarray          # (L, 2, D) forward-stream tails
-    fqs: jnp.ndarray          # (L, 2, D, Cf, 4) stream entries, packed
+    fqs: jnp.ndarray          # (L·2·D·Cf·4,) stream entries, packed
     #                           channels: 0 release time, 1 route id
     #                           (dest | mcast tree), 2 original injection
     #                           time, 3 reference-slot tie key.  One array
@@ -1302,7 +1332,11 @@ class _RingState(NamedTuple):
     #                           scatter/gather rows dominate the step on
     #                           CPU, and under vmap they serialize per
     #                           instance, so row count is the batch
-    #                           throughput limit.
+    #                           throughput limit.  Flat, because as a
+    #                           5-D array the head gather and the tail
+    #                           scatter asked for different layouts and
+    #                           XLA on the TPU copied the whole buffer
+    #                           between them on every step.
     n_ins: jnp.ndarray        # (L, 2) entries ever inserted (capacity/key)
     sent: jnp.ndarray         # (L, 2)
     prev_mode_l: jnp.ndarray  # (L,)
@@ -1370,16 +1404,15 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
         q0_all = jnp.stack([q0_time, q0_dest, q0_inj], axis=-1)
         didx = jnp.arange(D, dtype=jnp.int32)
         qid = jnp.arange(Q, dtype=jnp.int32)[None, :]
+        sid = jnp.arange(Q * D, dtype=jnp.int32)
+        ch4 = jnp.arange(4, dtype=jnp.int32)[None, :]
         init = _RingState(
             link=link0,
             h0=jnp.zeros((L, 2), jnp.int32),
             fh=jnp.zeros((L, 2, D), jnp.int32),
             ftl=jnp.zeros((L, 2, D), jnp.int32),
-            fqs=jnp.stack(
-                [jnp.full((L, 2, D, Cf), _BIG, jnp.int32),
-                 jnp.zeros((L, 2, D, Cf), jnp.int32),
-                 jnp.zeros((L, 2, D, Cf), jnp.int32),
-                 jnp.zeros((L, 2, D, Cf), jnp.int32)], axis=-1),
+            fqs=jnp.where(jnp.arange(Q * D * Cf * 4) % 4 == 0,
+                          _BIG, 0).astype(jnp.int32),
             n_ins=sizes,
             sent=jnp.zeros((L, 2), jnp.int32),
             prev_mode_l=link0.xl.mode,
@@ -1409,8 +1442,8 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
             # heads — no O(C) slot scan.
             p_head = jnp.take_along_axis(
                 q0_all, s.h0[:, :, None, None], axis=2)[:, :, 0]  # (L,2,3)
-            f_head = jnp.take_along_axis(
-                s.fqs, s.fh[..., None, None], axis=3)[:, :, :, 0]  # (L,2,D,4)
+            f_head = s.fqs[(sid * Cf + s.fh.reshape(-1))[:, None] * 4
+                           + ch4].reshape(L, 2, D, 4)            # (L,2,D,4)
             p_t = p_head[..., 0]                                 # (L, 2)
             f_t = f_head[..., 0]                                 # (L, 2, D)
             p_rel = p_t <= t_now[:, None]
@@ -1573,9 +1606,8 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
             upd = jnp.stack(
                 [jnp.repeat(link.t, K), jnp.repeat(ev_route, K),
                  jnp.repeat(ev_inj, K), key], axis=-1)           # (L·K, 4)
-            fqs = s.fqs.reshape(Q * D, Cf, 4) \
-                .at[stream_s, tail].set(upd, mode="drop") \
-                .reshape(L, 2, D, Cf, 4)
+            fqs = s.fqs.at[(stream_s * Cf + tail)[:, None] * 4 + ch4].set(
+                upd, mode="drop")
             # counter bumps as dense one-hot sums over the tiny (Q,) and
             # (D,) index spaces — masked rows contribute zero everywhere
             eq_q = fq_g[:, None] == qid                          # (L·K, Q)
@@ -1723,10 +1755,8 @@ def _ring_run_batch(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
 @functools.lru_cache(maxsize=None)
 def _ring_engine(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
     """Compile-once ring simulation for one static shape signature —
-    :func:`_ring_run` jitted.  No donation: the prefill arrays are
-    read-only gather sources here (no same-shaped output exists to alias
-    them into)."""
-    return _jit_cached(_ring_run(L, E, C0, D, Cf, chunk))
+    :func:`_ring_run` jitted."""
+    return jax.jit(_ring_run(L, E, C0, D, Cf, chunk))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1748,8 +1778,8 @@ def _ring_engine_batch(L: int, E: int, C0: int, D: int, Cf: int,
     ``n_devices > 1`` the batch axis is sharded across devices and each
     shard drains independently (see :func:`_shard_over_batch`)."""
     fn = _ring_run_batch(L, E, C0, D, Cf, chunk)
-    return _jit_cached(_shard_over_batch(fn, n_devices, n_args=19,
-                                         replicated=(16,)))
+    return jax.jit(_shard_over_batch(fn, n_devices, n_args=19,
+                                     replicated=(16,)))
 
 
 # -----------------------------------------------------------------------

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops as K
-from ..parallel.compat import axis_size
 from . import halfduplex as hd
 
 
@@ -49,7 +48,7 @@ def aer_allreduce(x, state: AerState, axis_name, *, frac=0.02,
     Returns (dense mean-reduced tensor — identical on all axis members,
     new AerState, wire_words_sent scalar).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     y = x + state.residual
     tiles, size = K.pad_to_blocks(y, block)
     tau = K.tau_from_fraction(tiles, frac)
@@ -59,14 +58,18 @@ def aer_allreduce(x, state: AerState, axis_name, *, frac=0.02,
     all_idx = jax.lax.all_gather(ev.idx, axis_name)    # (n, nb, budget)
     all_val = jax.lax.all_gather(ev.val, axis_name)
 
-    dec_all = jax.vmap(
-        lambda i, v: K.aer_decompress(K.EventBlocks(i, v, ev.count,
-                                                    ev.wanted),
-                                      block, interpret=interpret)
-    )(all_idx, all_val)                                # (n, nb, block)
-    summed = dec_all.sum(axis=0) / n
+    # decode the peers one at a time into one accumulator: an (nb, block)
+    # buffer, not n of them, beside the gradient
+    def add_peer(acc, iv):
+        dec = K.aer_decompress(K.EventBlocks(*iv, ev.count, ev.wanted),
+                               block, interpret=interpret)
+        return acc + dec, None
 
-    own_dec = dec_all[jax.lax.axis_index(axis_name)]
+    summed, _ = jax.lax.scan(add_peer, jnp.zeros_like(tiles),
+                             (all_idx, all_val))
+    summed = summed / n
+
+    own_dec = K.aer_decompress(ev, block, interpret=interpret)
     new_residual = K.unpad_from_blocks(tiles - own_dec, size, x.shape)
     reduced = K.unpad_from_blocks(summed, size, x.shape)
     wire_words = jnp.sum(ev.count)
@@ -75,7 +78,7 @@ def aer_allreduce(x, state: AerState, axis_name, *, frac=0.02,
 
 def dense_allreduce(x, axis_name, *, schedule="psum"):
     """Dense mean baselines: psum | ring | bidir_ring."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if schedule == "psum":
         return jax.lax.psum(x, axis_name) / n
     return hd.ring_allreduce(

@@ -1,13 +1,15 @@
 """Pallas TPU kernel: AER event decoder (RX path of the transceiver).
 
 Accumulates fixed-width event slots back into a dense block:
-``dense[r, b] = sum_e [idx[r, e] == b] * val[r, e]``.  As with the encoder,
-the gather/scatter is recast as a one-hot contraction so the accumulation
-runs on the MXU; duplicate addresses therefore sum naturally (the AER
-semantics — two spikes at one address are two contributions).
+``dense[r, b] = sum_e [idx[r, e] == b] * val[r, e]``, one row at a time
+as a masked reduction over the (block, budget) plane (Mosaic has no
+scatter); duplicate addresses therefore sum naturally (the AER
+semantics — two spikes at one address are two contributions), and a
+single event lands bit-exactly.
 
-VMEM per grid step (rows_per_block=4, budget=128, block=1024): one-hot
-2 MiB + slots 4 KiB.  idx == -1 marks a void slot (matches no address).
+VMEM per grid step (rows_per_block=8, budget=128, block=1024): one
+(block, budget) plane 512 KiB per row.  idx == -1 marks a void slot
+(matches no address).
 """
 
 from __future__ import annotations
@@ -21,23 +23,19 @@ from .dispatch import resolve_interpret
 
 def _decode_kernel(idx_ref, val_ref, out_ref):
     idx = idx_ref[...]                  # (rows, budget) i32
-    val = val_ref[...]                  # (rows, budget)
+    val = val_ref[...].astype(jnp.float32)
     rows, budget = idx.shape
     block = out_ref.shape[-1]
 
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (rows, budget, block), 2)
-    onehot = ((idx[:, :, None] == iota_b) & (idx[:, :, None] >= 0)).astype(
-        jnp.float32)
-
-    dense = jax.lax.dot_general(
-        val.astype(jnp.float32)[:, None, :], onehot,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)[:, 0, :]
-    out_ref[...] = dense.astype(out_ref.dtype)
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (block, budget), 0)
+    dense = [jnp.sum(jnp.where(idx[r:r + 1, :] == iota_b,
+                               val[r:r + 1, :], 0.0), axis=1)
+             for r in range(rows)]
+    out_ref[...] = jnp.stack(dense).astype(out_ref.dtype)
 
 
 def aer_decode_pallas(idx: jnp.ndarray, val: jnp.ndarray, block: int,
-                      *, rows_per_block: int = 4,
+                      *, rows_per_block: int = 8,
                       interpret: bool | str | None = None):
     """idx/val: (num_blocks, budget); returns dense (num_blocks, block)."""
     nb, budget = idx.shape

@@ -7,10 +7,9 @@ through :func:`resolve_interpret`:
   everywhere (the escape hatch for debugging a compiled backend);
   ``PALLAS_INTERPRET=0`` forces the compiled path.
 * ``None`` / ``"auto"`` picks the compiled path exactly when the active
-  JAX backend has a Pallas compiler (TPU via Mosaic, GPU via Triton) and
-  interpret mode otherwise — this container is CPU-only, so auto means
-  interpret here, but the same wheels on a TPU/GPU host stop silently
-  interpreting every kernel.
+  JAX backend is a TPU (the kernels are written for Mosaic: TPU memory
+  spaces and tilings) and interpret mode otherwise — on the CPU, where
+  the tests run, auto means interpret.
 * An explicit ``True`` / ``False`` is honoured as-is (absent the env
   override).
 
@@ -24,10 +23,7 @@ import os
 
 import jax
 
-__all__ = ["COMPILED_BACKENDS", "resolve_interpret"]
-
-#: backends with a Pallas compiler: Mosaic (TPU) and Triton (GPU).
-COMPILED_BACKENDS = frozenset({"tpu", "gpu", "cuda", "rocm"})
+__all__ = ["resolve_interpret"]
 
 
 def resolve_interpret(interpret: bool | str | None = None) -> bool:
@@ -40,5 +36,5 @@ def resolve_interpret(interpret: bool | str | None = None) -> bool:
     if env is not None and env.strip() != "":
         return env.strip() not in ("0", "false", "False")
     if interpret is None or interpret == "auto":
-        return jax.default_backend() not in COMPILED_BACKENDS
+        return jax.default_backend() != "tpu"
     return bool(interpret)

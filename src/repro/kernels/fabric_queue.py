@@ -29,17 +29,21 @@ TPU adaptation notes (mirroring ``aer_encode.py``):
 * The update kernel recasts both scatters as ONE-HOT MATMULS (VMEM has
   no scatter): with ``A[r, l] = [pop_q[l] == r]`` and
   ``S[l, c] = [pop_slot[l] == c]``, the pop mask is ``A @ S`` and the
-  append values are ``(B * value) @ S_app`` — (rows × links × C)
-  contractions that run on the MXU.  All arithmetic stays int32 so
-  release times up to the ``BIG_NS`` sentinel (2**30) survive exactly
-  (an f32 accumulator's 24-bit mantissa would corrupt them).
+  append values are ``(A * value) @ S_app`` — (rows × links × C)
+  contractions that run on the MXU.  The MXU has no int32 matmul, so
+  each int32 value is scattered as its four bytes in bf16 with f32
+  accumulation: every output sums at most one nonzero term (targets
+  are unique), and a byte is exact in bf16, so release times up to the
+  ``BIG_NS`` sentinel (2**30) survive bit for bit.
 * Out-of-range ids (the caller's "no pop / no append on this link"
   sentinel ``Q``; dropped forwards) simply match no row — the one-hot
   formulation gives masked scatter for free.
 
 Validated bit-exactly against ``ref.fabric_queue_scan`` /
-``ref.fabric_queue_update`` in interpret mode (CPU container); the
-grid/BlockSpec layout is the TPU deployment configuration.
+``ref.fabric_queue_update`` in interpret mode on the CPU, and compiled
+for a TPU v5e by ``tests/test_tpu_compile.py``.  Row blocks are 8 rows
+(or the whole array) and per-row results are (rows, 1) columns, the
+tiling Mosaic accepts.
 
 These kernels back ``engine="pallas"`` of the fabric front-end
 (``fabric.EngineSpec`` / the ``simulate_fabric`` wrapper).  They are
@@ -56,6 +60,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.protocol_sim import BIG_NS
 from .dispatch import resolve_interpret
@@ -94,16 +99,18 @@ def scan_math(q, qd, t):
     return pend, row_min, nxt, amin, busy, route
 
 
-def _scan_kernel(q_ref, qd_ref, t_ref, pend_ref, rmin_ref, nxt_ref,
-                 amin_ref, busy_ref, route_ref):
-    pend, r_min, nxt, amin, busy, route = scan_math(
-        q_ref[...], qd_ref[...], t_ref[...])
-    pend_ref[...] = pend
-    rmin_ref[...] = r_min
-    nxt_ref[...] = nxt
-    amin_ref[...] = amin
-    busy_ref[...] = busy
-    route_ref[...] = route
+def _scan_kernel(q_ref, qd_ref, t_ref, *out_refs):
+    outs = scan_math(q_ref[...], qd_ref[...], t_ref[...][:, 0])
+    for o_ref, o in zip(out_refs, outs):
+        o_ref[...] = o[:, None]
+
+
+def _row_block(nq: int, rows_per_block: int) -> int:
+    """Rows per grid step: ``rows_per_block`` when it tiles ``nq`` in
+    multiples of 8 (the TPU sublane tile), else the whole array."""
+    if rows_per_block % 8 == 0 and nq % rows_per_block == 0:
+        return rows_per_block
+    return nq
 
 
 def fabric_queue_step_pallas(q_time: jnp.ndarray, q_dest: jnp.ndarray,
@@ -123,25 +130,49 @@ def fabric_queue_step_pallas(q_time: jnp.ndarray, q_dest: jnp.ndarray,
     flow-control gate).
     """
     nq, _ = q_time.shape
-    assert nq % rows_per_block == 0, (nq, rows_per_block)
-    grid = (nq // rows_per_block,)
+    rows = _row_block(nq, rows_per_block)
+    grid = (nq // rows,)
 
-    out_shape = [jax.ShapeDtypeStruct((nq,), jnp.int32) for _ in range(6)]
-    row_spec = pl.BlockSpec((rows_per_block,), lambda i: (i,))
-    tile = pl.BlockSpec((rows_per_block, q_time.shape[1]), lambda i: (i, 0))
-    return pl.pallas_call(
+    out_shape = [jax.ShapeDtypeStruct((nq, 1), jnp.int32) for _ in range(6)]
+    col_spec = pl.BlockSpec((rows, 1), lambda i: (i, 0))
+    tile = pl.BlockSpec((rows, q_time.shape[1]), lambda i: (i, 0))
+    outs = pl.pallas_call(
         _scan_kernel,
         grid=grid,
-        in_specs=[tile, tile, row_spec],
-        out_specs=[row_spec] * 6,
+        in_specs=[tile, tile, col_spec],
+        out_specs=[col_spec] * 6,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
-    )(q_time, q_dest, t_q)
+    )(q_time, q_dest, t_q[:, None])
+    return tuple(o[:, 0] for o in outs)
+
+
+_DN = (((1,), (0,)), ((), ()))
+
+
+def _onehot_dot(a, s):
+    """``a @ s`` for small non-negative int32 operands (0/1 or a byte,
+    exact in bf16) on the MXU, accumulated in f32: exact while every
+    output stays below 2**24."""
+    return jax.lax.dot_general(
+        a.astype(jnp.bfloat16), s.astype(jnp.bfloat16), _DN,
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _onehot_scatter(a, vals, s):
+    """``(a * vals) @ s`` for one-hot ``a``/``s`` with unique targets:
+    the four bytes of each int32 value go through :func:`_onehot_dot`
+    separately and are reassembled, so every int32 is exact."""
+    out = jnp.zeros((a.shape[0], s.shape[1]), jnp.int32)
+    for shift in (0, 8, 16, 24):
+        byte = jax.lax.shift_right_logical(vals, shift) & 0xFF
+        out = out | (_onehot_dot(a * byte[None, :], s) << shift)
+    return out
 
 
 def update_math(qt, qd, qi, popq, pops, appq, apps, appt, appd, appi,
                 row_base=0):
-    """Value-level body of the update kernel (scatter-as-matmul, int32).
+    """Value-level body of the update kernel (scatter-as-matmul).
 
     ``row_base`` offsets the tile's row ids when the caller processes a
     (rows, C) slice of a larger array (the gridded per-step kernel); the
@@ -157,27 +188,19 @@ def update_math(qt, qd, qi, popq, pops, appq, apps, appt, appd, appi,
 
     iota_pop = jax.lax.broadcasted_iota(jnp.int32, (n_pop, ncols), 1)
     iota_app = jax.lax.broadcasted_iota(jnp.int32, (n_app, ncols), 1)
-    dn = (((1,), (0,)), ((), ()))
 
-    # scatter-as-matmul, int32 end to end (exact for times < 2**31)
     a_pop = (row_ids == popq[None, :]).astype(jnp.int32)     # (rows, Lp)
     s_pop = (pops[:, None] == iota_pop).astype(jnp.int32)    # (Lp, C)
-    p_pop = jax.lax.dot_general(a_pop, s_pop, dn,
-                                preferred_element_type=jnp.int32)
+    p_pop = _onehot_dot(a_pop, s_pop)
 
     a_app = (row_ids == appq[None, :]).astype(jnp.int32)     # (rows, La)
     s_app = (apps[:, None] == iota_app).astype(jnp.int32)    # (La, C)
-    p_app = jax.lax.dot_general(a_app, s_app, dn,
-                                preferred_element_type=jnp.int32)
-
-    def scatter(vals):
-        return jax.lax.dot_general(a_app * vals[None, :], s_app, dn,
-                                   preferred_element_type=jnp.int32)
+    p_app = _onehot_dot(a_app, s_app)
 
     keep = 1 - p_pop - p_app             # pop/append slots are disjoint
-    return (qt * keep + _BIG * p_pop + scatter(appt),
-            qd * (1 - p_app) + scatter(appd),
-            qi * (1 - p_app) + scatter(appi))
+    return (qt * keep + _BIG * p_pop + _onehot_scatter(a_app, appt, s_app),
+            qd * (1 - p_app) + _onehot_scatter(a_app, appd, s_app),
+            qi * (1 - p_app) + _onehot_scatter(a_app, appi, s_app))
 
 
 def _update_kernel(qt_ref, qd_ref, qi_ref, popq_ref, pops_ref,
@@ -209,11 +232,11 @@ def fabric_queue_update_pallas(q_time, q_dest, q_inj,
     slot).  Returns the three updated arrays.
     """
     nq, ncols = q_time.shape
-    assert nq % rows_per_block == 0, (nq, rows_per_block)
-    grid = (nq // rows_per_block,)
+    rows = _row_block(nq, rows_per_block)
+    grid = (nq // rows,)
 
-    kernel = functools.partial(_update_kernel, rows_per_block=rows_per_block)
-    tile = pl.BlockSpec((rows_per_block, ncols), lambda i: (i, 0))
+    kernel = functools.partial(_update_kernel, rows_per_block=rows)
+    tile = pl.BlockSpec((rows, ncols), lambda i: (i, 0))
     whole_pop = pl.BlockSpec((pop_q.shape[0],), lambda i: (0,))
     whole_app = pl.BlockSpec((app_q.shape[0],), lambda i: (0,))
     out_shape = [jax.ShapeDtypeStruct((nq, ncols), jnp.int32)
@@ -235,8 +258,36 @@ def fabric_queue_update_pallas(q_time, q_dest, q_inj,
 # Multi-step fused kernel: the whole micro-transaction loop per launch
 # ---------------------------------------------------------------------------
 
+#: Mosaic's default scoped-VMEM limit per kernel.
+VMEM_DEFAULT_BYTES = 16 << 20
+#: Scoped VMEM the multi-step kernel may ask for: most of the 128 MiB of
+#: one TPU v5e core, leaving room for the compiler's own buffers.
+VMEM_MAX_BYTES = 100 << 20
+#: Scoped VMEM the kernel needs beyond its operands and temporaries.
+VMEM_FIXED_BYTES = 1 << 20
+
+
+def multistep_vmem_bytes(carry, consts, step_temp_bytes: int = 0) -> int:
+    """Scoped VMEM the multi-step kernel is budgeted for these operands.
+
+    The carry is counted four times (the input and output blocks and the
+    step loop's state before and after a step), the constants once, and
+    ``step_temp_bytes`` — the step function's largest temporaries, which
+    its caller knows — six times (an iota-compare mask, the selected
+    values and the reduction, for up to two index columns at once).
+    Compiled for a v5e, Mosaic asked for 4.2x to 7.9x the carry on
+    ring-16 (1,024 to 32,768 events), ring-32, ring-64 and 4x4 and 8x8
+    meshes; this budget sits 26% to 55% above each of those asks.
+    """
+    def nbytes(arrs):
+        return sum(a.size * a.dtype.itemsize for a in arrs)
+    return (4 * nbytes(carry) + nbytes(consts) + 6 * step_temp_bytes
+            + VMEM_FIXED_BYTES)
+
+
 def fabric_queue_multistep_pallas(carry, consts, base, *, step_fn,
                                   chunk: int, max_steps: int,
+                                  step_temp_bytes: int = 0,
                                   interpret: bool | str | None = None):
     """Run up to ``chunk`` fabric micro-transactions in ONE kernel launch.
 
@@ -262,7 +313,8 @@ def fabric_queue_multistep_pallas(carry, consts, base, *, step_fn,
               side / log / counter planes — the caller owns the layout).
       consts: tuple of read-only int32 arrays (links, replication
               tables, timing planes, flow-control scalars).
-      base:   (1,) int32 — global index of this chunk's first step.
+      base:   (1,) int32 — global index of this chunk's first step; it
+              rides in SMEM, so the kernel reads it as a scalar.
       step_fn: ``step_fn(carry, consts, step_i) -> carry`` — one
               micro-transaction of physics, built by the engine so the
               kernel body and the pure-jnp oracle
@@ -275,6 +327,14 @@ def fabric_queue_multistep_pallas(carry, consts, base, *, step_fn,
               ``min(chunk, max_steps - base)`` — dynamic, so a binding
               ``max_steps`` is honoured exactly (post-bound steps are
               NOT executed; they are not guaranteed to be no-ops).
+      step_temp_bytes: bytes of the largest temporaries one
+              ``step_fn`` call makes (its one-hot masks), for the VMEM
+              budget.
+
+    The whole carry lives in VMEM, so on a compiled backend a budget
+    (:func:`multistep_vmem_bytes`) above ``VMEM_MAX_BYTES`` raises
+    ``ValueError``; one above Mosaic's default limit raises the kernel's
+    limit to the budget.
 
     Returns the stepped carry tuple (same shapes/dtypes).
     """
@@ -282,6 +342,19 @@ def fabric_queue_multistep_pallas(carry, consts, base, *, step_fn,
     consts = tuple(consts)
     n_car = len(carry)
     n_con = len(consts)
+    interpret = resolve_interpret(interpret)
+    params = None
+    if not interpret:
+        need = multistep_vmem_bytes(carry, consts, step_temp_bytes)
+        if need > VMEM_MAX_BYTES:
+            raise ValueError(
+                f"the multi-step fabric kernel keeps its whole carry in "
+                f"VMEM and is budgeted {need} bytes for these shapes "
+                f"(carry {[a.shape for a in carry]}), above the "
+                f"{VMEM_MAX_BYTES}-byte limit; use kernel='step' or the "
+                f"ring engine for a fabric this large")
+        if need > VMEM_DEFAULT_BYTES:
+            params = pltpu.CompilerParams(vmem_limit_bytes=need)
 
     def kernel(*refs):
         car = tuple(r[...] for r in refs[:n_car])
@@ -298,8 +371,13 @@ def fabric_queue_multistep_pallas(carry, consts, base, *, step_fn,
             o_ref[...] = o
 
     out_shape = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in carry]
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
+        in_specs=[vmem] * (n_car + n_con)
+        + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[vmem] * n_car,
         out_shape=out_shape,
-        interpret=resolve_interpret(interpret),
+        compiler_params=params,
+        interpret=interpret,
     )(*carry, *consts, base)

@@ -5,10 +5,9 @@ threshold selection, event packing (26-bit-style wire words), and the
 error-feedback compose used by the sparse collectives.
 
 ``interpret=None`` auto-selects via ``dispatch.resolve_interpret``:
-compiled wherever a Pallas backend exists (TPU/GPU), interpret elsewhere
-(this container is CPU-only; the BlockSpec layout is the TPU deployment
-config).  ``PALLAS_INTERPRET=1`` forces interpret mode everywhere — note
-it is read when a wrapper first traces, so set it before the first call.
+compiled on a TPU, interpret mode on the CPU.  ``PALLAS_INTERPRET=1``
+forces interpret mode everywhere — note it is read when a wrapper first
+traces, so set it before the first call.
 """
 
 from __future__ import annotations
@@ -72,38 +71,49 @@ def tau_from_fraction(x_tiles: jnp.ndarray, frac: float):
         x_tiles.dtype)
 
 
+def _pad_rows(x: jnp.ndarray, rows: int, fill=0):
+    """Pad the leading axis up to a multiple of ``rows`` with ``fill``."""
+    pad = -x.shape[0] % rows
+    if not pad:
+        return x
+    return jnp.concatenate(
+        [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
+
+
 @functools.partial(jax.jit, static_argnames=("budget", "interpret",
                                              "rows_per_block", "use_ref"))
 def aer_compress(x_tiles: jnp.ndarray, tau: jnp.ndarray,
                  budget: int = DEFAULT_BUDGET, *, interpret: bool | None = None,
-                 rows_per_block: int = 4, use_ref: bool = False) -> EventBlocks:
-    """Encode (num_blocks, block) tiles into event slots."""
+                 rows_per_block: int = 8, use_ref: bool = False) -> EventBlocks:
+    """Encode (num_blocks, block) tiles into event slots.
+
+    The kernel runs ``rows_per_block`` rows per grid step (a multiple of
+    8 on a TPU); zero rows pad the tail and ship no events."""
     if use_ref:
         out = ref.aer_encode(x_tiles, tau, budget)
     else:
         nb = x_tiles.shape[0]
-        rpb = rows_per_block
-        while nb % rpb:
-            rpb //= 2
-        out = aer_encode_pallas(x_tiles, tau, budget, rows_per_block=max(rpb, 1),
-                                interpret=_auto_interpret(interpret))
+        tau = jnp.asarray(tau, x_tiles.dtype).reshape(nb)
+        out = aer_encode_pallas(
+            _pad_rows(x_tiles, rows_per_block), _pad_rows(tau, rows_per_block),
+            budget, rows_per_block=rows_per_block,
+            interpret=_auto_interpret(interpret))
+        out = tuple(o[:nb] for o in out)
     return EventBlocks(*out)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret",
                                              "rows_per_block", "use_ref"))
 def aer_decompress(events_: EventBlocks, block: int = DEFAULT_BLOCK, *,
-                   interpret: bool | None = None, rows_per_block: int = 4,
+                   interpret: bool | None = None, rows_per_block: int = 8,
                    use_ref: bool = False) -> jnp.ndarray:
     if use_ref:
         return ref.aer_decode(events_.idx, events_.val, block)
     nb = events_.idx.shape[0]
-    rpb = rows_per_block
-    while nb % rpb:
-        rpb //= 2
-    return aer_decode_pallas(events_.idx, events_.val, block,
-                             rows_per_block=max(rpb, 1),
-                             interpret=_auto_interpret(interpret))
+    return aer_decode_pallas(_pad_rows(events_.idx, rows_per_block, -1),
+                             _pad_rows(events_.val, rows_per_block), block,
+                             rows_per_block=rows_per_block,
+                             interpret=_auto_interpret(interpret))[:nb]
 
 
 def compress_with_feedback(x: jnp.ndarray, residual: jnp.ndarray, *,
@@ -122,13 +132,6 @@ def compress_with_feedback(x: jnp.ndarray, residual: jnp.ndarray, *,
     dec = aer_decompress(events_, block, interpret=interpret)
     new_res = unpad_from_blocks(tiles - dec, n, x.shape)
     return events_, new_res, n
-
-
-def _rows_per_block_for(nq: int, rows_per_block: int) -> int:
-    rpb = rows_per_block
-    while nq % rpb:
-        rpb //= 2
-    return max(rpb, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "use_ref",
@@ -154,7 +157,7 @@ def fabric_queue_scan(q_time: jnp.ndarray, q_dest: jnp.ndarray,
         return ref.fabric_queue_scan(q_time, q_dest, t_q)
     return fabric_queue_step_pallas(
         q_time, q_dest, t_q,
-        rows_per_block=_rows_per_block_for(q_time.shape[0], rows_per_block),
+        rows_per_block=rows_per_block,
         interpret=_auto_interpret(interpret))
 
 
@@ -181,7 +184,7 @@ def fabric_queue_update(q_time, q_dest, q_inj, pop_q, pop_slot,
     return fabric_queue_update_pallas(
         q_time, q_dest, q_inj, pop_q, pop_slot,
         app_q, app_slot, app_t, app_dest, app_inj,
-        rows_per_block=_rows_per_block_for(q_time.shape[0], rows_per_block),
+        rows_per_block=rows_per_block,
         interpret=_auto_interpret(interpret))
 
 

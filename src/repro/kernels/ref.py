@@ -37,9 +37,14 @@ def aer_encode(x: jnp.ndarray, tau: jnp.ndarray, budget: int):
     # one-hot scatter: slot e receives the entry whose dest == e
     onehot = (dest[:, :, None] == iota_e[None, None, :]) & sel[:, :, None]
     onehot_f = onehot.astype(jnp.float32)
-    val = jnp.einsum("rbe,rb->re", onehot_f, x.astype(jnp.float32))
+    # HIGHEST: a one-hot contraction is exact only at full f32 precision
+    # (a TPU's default rounds f32 matmul operands to bf16)
+    val = jnp.einsum("rbe,rb->re", onehot_f, x.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     iota_b = jnp.arange(blk, dtype=jnp.float32) + 1.0
-    idx = jnp.einsum("rbe,b->re", onehot_f, iota_b).astype(jnp.int32) - 1
+    idx = jnp.einsum("rbe,b->re", onehot_f, iota_b,
+                     precision=jax.lax.Precision.HIGHEST
+                     ).astype(jnp.int32) - 1
 
     wanted = csum[:, -1]
     count = jnp.minimum(wanted, budget)
@@ -56,7 +61,8 @@ def aer_decode(idx: jnp.ndarray, val: jnp.ndarray, block: int):
     iota_b = jnp.arange(block, dtype=jnp.int32)
     onehot = (idx[:, :, None] == iota_b[None, None, :]) & (idx[:, :, None] >= 0)
     dense = jnp.einsum("reb,re->rb", onehot.astype(jnp.float32),
-                       val.astype(jnp.float32))
+                       val.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
     return dense.astype(val.dtype)
 
 

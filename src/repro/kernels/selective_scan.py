@@ -20,10 +20,11 @@ production chunked-associative-scan path (tests/test_kernels_scan.py).
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .dispatch import resolve_interpret
 
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_ref):
@@ -49,7 +50,7 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_ref):
 
 
 def selective_scan_pallas(x, dt, b_ssm, c_ssm, a, *, d_block: int = 512,
-                          interpret: bool = True):
+                          interpret: bool | str | None = None):
     """x, dt: (B, S, d_in) f32; b_ssm/c_ssm: (B, S, N); a: (d_in, N).
 
     Returns (y: (B, S, d_in) f32, h_final: (B, d_in, N) f32).
@@ -78,5 +79,5 @@ def selective_scan_pallas(x, dt, b_ssm, c_ssm, a, *, d_block: int = 512,
             jax.ShapeDtypeStruct((B, S, d_in), jnp.float32),
             jax.ShapeDtypeStruct((B, d_in, N), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, b_ssm, c_ssm, a)

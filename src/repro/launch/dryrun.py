@@ -39,7 +39,6 @@ from ..configs.base import (ALL_SHAPES, ARCH_IDS, RunConfig, get_config,
                             input_specs, shapes_for)
 from ..models.model import build_model
 from ..optim import adamw
-from ..parallel.compat import set_mesh
 from ..parallel.sharding import make_rules, partition_params, use_rules
 from ..runtime.train_loop import TrainState, make_train_step
 from .mesh import make_production_mesh
@@ -202,7 +201,7 @@ def lower_cell(arch: str, shape_name: str, mesh, run_cfg: RunConfig,
             step=jax.ShapeDtypeStruct((), jnp.int32,
                                       sharding=NamedSharding(mesh, P())))
         step_fn = make_train_step(model, run_cfg, rules)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = step_fn.lower(state_abs, batch_abs)
         return lowered, meta
 
@@ -219,7 +218,7 @@ def lower_cell(arch: str, shape_name: str, mesh, run_cfg: RunConfig,
                 with use_rules(rules):
                     return model.prefill(p, b, max_len=shape.seq_len)
             fn = jax.jit(prefill)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(params_in, batch_abs)
         return lowered, meta
 
@@ -240,7 +239,7 @@ def lower_cell(arch: str, shape_name: str, mesh, run_cfg: RunConfig,
             return model.decode_step(p, c, t, q)
 
     fn = jax.jit(decode)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(params_in, cache_in, tok, pos)
     return lowered, meta
 

@@ -1,7 +1,7 @@
 """Serving entry point: batched prefill + decode loop.
 
-CPU-scale demo of the full serving path every decode-shape dry-run cell
-lowers: prefill a batch of prompts, then step the KV/SSM caches token by
+The full serving path every decode-shape dry-run cell lowers, at
+published widths (or ``--smoke`` widths on a CPU): prefill a batch of prompts, then step the KV/SSM caches token by
 token with greedy sampling.  The same step functions are what the
 ``decode_32k`` / ``long_500k`` cells compile for the production mesh.
 
@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,14 @@ from ..data import SyntheticLM
 from ..models.model import build_model
 
 
-def main(argv=None):
+class ServeResult(NamedTuple):
+    tokens: jax.Array        # (batch, gen) int32 greedy tokens
+    logits: jax.Array        # (batch, 1, vocab_padded) last decode logits
+    param_bytes: int         # bytes of the weights on the device
+    tok_per_s: float         # decode tokens per second (batch summed)
+
+
+def main(argv=None) -> ServeResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -35,7 +43,13 @@ def main(argv=None):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     assert cfg.causal, f"{args.arch} is encoder-only — nothing to decode"
     model = build_model(cfg)
-    params, _ = model.init(jax.random.PRNGKey(args.seed))
+    # one jitted init: the full-width weights are generated in place on
+    # the device, with no eager per-op temporaries beside them
+    params = jax.jit(lambda k: model.init(k)[0])(
+        jax.random.PRNGKey(args.seed))
+    param_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
+    print(f"{cfg.name}: {param_bytes} parameter bytes on "
+          f"{jax.devices()[0].device_kind}")
 
     data = SyntheticLM(cfg.vocab, args.prompt_len, args.batch,
                        seed=args.seed, modality=cfg.modality,
@@ -51,6 +65,7 @@ def main(argv=None):
     t0 = time.time()
     logits, cache = prefill(params, batch)
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+    jax.block_until_ready(tok)
     t_prefill = time.time() - t0
 
     out_tokens = [tok]
@@ -64,13 +79,13 @@ def main(argv=None):
     t_decode = time.time() - t0
 
     gen = jnp.concatenate(out_tokens, axis=1)
+    tok_per_s = (args.gen - 1) * args.batch / max(t_decode, 1e-9)
     print(f"{cfg.name}: prefill({args.batch}x{args.prompt_len}) "
           f"{t_prefill*1e3:.1f} ms; decode {args.gen - 1} steps "
-          f"{t_decode*1e3:.1f} ms "
-          f"({(args.gen - 1) * args.batch / max(t_decode, 1e-9):.1f} tok/s)")
+          f"{t_decode*1e3:.1f} ms ({tok_per_s:.1f} tok/s)")
     for b in range(min(args.batch, 2)):
         print(f"  seq{b}: {list(map(int, gen[b][:12]))}")
-    return gen
+    return ServeResult(gen, logits, param_bytes, tok_per_s)
 
 
 if __name__ == "__main__":

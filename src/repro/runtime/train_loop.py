@@ -27,7 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import sparse_collectives as sc
 from ..optim import adamw
-from ..parallel.compat import shard_map
 from ..parallel.sharding import Rules, partition_params, use_rules
 
 
@@ -121,15 +120,29 @@ def make_train_step(model, run_cfg, rules: Rules | None = None):
         return jax.jit(step)
 
     # ---------------- manual DP (paper technique schedules) -------------
-    # shard_map is MANUAL over the DP axes only (axis_names); the model
-    # axis stays automatic so TP constraints keep working.  Inside the
-    # manual region the per-shard batch is local — its logical "batch"
-    # axis maps to nothing.
+    # shard_map is MANUAL over the DP axes and every size-1 axis: a Pallas
+    # kernel in the region (the aer_topk codec) cannot be partitioned
+    # automatically, and a size-1 axis partitions nothing.  A model axis
+    # wider than 1 stays automatic so TP constraints keep working.  Inside
+    # the manual region the per-shard batch is local, and the activation
+    # rules drop every manual axis (the logical "batch" axis maps to
+    # nothing).
     import dataclasses
 
     axis_name = dp_axes if len(dp_axes) > 1 else dp_axes[0]
+    manual_axes = frozenset(dp_axes) | {
+        a for a in mesh.axis_names if mesh.shape[a] == 1}
+
+    def auto_only(v):
+        axes = v if isinstance(v, tuple) else (v,)
+        kept = tuple(a for a in axes if a is not None
+                     and a not in manual_axes)
+        if not kept:
+            return None
+        return kept if isinstance(v, tuple) else kept[0]
+
     inner_rules = dataclasses.replace(
-        rules, act_map={**rules.act_map, "batch": None})
+        rules, act_map={k: auto_only(v) for k, v in rules.act_map.items()})
 
     def manual(state, batch):
         with use_rules(inner_rules):
@@ -142,9 +155,9 @@ def make_train_step(model, run_cfg, rules: Rules | None = None):
                     jax.tree.map(lambda _: batch_spec, batch))
         out_specs = (jax.tree.map(lambda _: P(), state),
                      {k: P() for k in METRIC_KEYS})
-        fn = shard_map(manual, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False,
-                       axis_names=frozenset(dp_axes))
+        fn = jax.shard_map(manual, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False,
+                           axis_names=manual_axes)
         return fn(state, batch)
 
     return jax.jit(stepped)

@@ -18,6 +18,7 @@ def run_with_devices(code: str, n_devices: int = 8, timeout=600) -> str:
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", "")).strip()
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"    # forced host devices: never the chip
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)
     if proc.returncode != 0:

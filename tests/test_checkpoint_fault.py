@@ -77,8 +77,9 @@ class TestCheckpointer:
         with tempfile.TemporaryDirectory() as d:
             ck = Checkpointer(d)
             ck.save(1, state, blocking=True)
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+            mesh = jax.make_mesh((1, 1), ("data", "model"),
+                                 axis_types=(AxisType.Auto,) * 2)
             sh = jax.tree.map(
                 lambda _: NamedSharding(mesh, P()), state)
             restored = ck.restore(1, state, shardings=sh)

@@ -11,15 +11,15 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import halfduplex as hd
-from repro.parallel.compat import AXIS_TYPE_AUTO, make_mesh, shard_map
+from jax.sharding import AxisType
 
-mesh = make_mesh((8,), ("data",), axis_types=(AXIS_TYPE_AUTO,))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 for shape in [(8, 64), (8, 37), (8, 1), (8, 1024)]:
     x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
     def run(fn):
-        f = shard_map(fn, mesh=mesh, in_specs=P("data", None),
+        f = jax.shard_map(fn, mesh=mesh, in_specs=P("data", None),
                       out_specs=P("data", None))
         return np.array(f(x))
 
@@ -32,7 +32,7 @@ for shape in [(8, 64), (8, 37), (8, 1), (8, 1024)]:
 
 # reduce-scatter places chunk i on device i
 x = jnp.tile(jnp.arange(8.0)[None], (8, 1))  # every device holds [0..7]
-@partial(shard_map, mesh=mesh, in_specs=P("data", None),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("data", None),
          out_specs=P("data"))
 def rs(t):
     return hd.ring_reduce_scatter(t[0], "data")
@@ -46,14 +46,14 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import sparse_collectives as sc
-from repro.parallel.compat import AXIS_TYPE_AUTO, make_mesh, shard_map
+from jax.sharding import AxisType
 
-mesh = make_mesh((8,), ("data",), axis_types=(AXIS_TYPE_AUTO,))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(1)
 g = jnp.asarray(rng.standard_normal((8, 4096)), jnp.float32)
 target = np.array(g).mean(axis=0)
 
-@partial(shard_map, check_vma=False, mesh=mesh,
+@partial(jax.shard_map, check_vma=False, mesh=mesh,
          in_specs=(P("data", None), P("data", None)),
          out_specs=(P("data", None), P("data", None), P("data")))
 def step(gl, res):

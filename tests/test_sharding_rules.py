@@ -3,14 +3,15 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.parallel.sharding import (make_rules, param_specs, partition_params,
                                      shard_activation, use_rules)
 
 
 def mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class TestRules:
@@ -26,7 +27,8 @@ class TestRules:
 
     def test_kv_fallback_to_seq_sharding(self):
         # tp=16 with 8 kv heads: activations replicate heads, shard cache seq
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
         r = make_rules(mesh, kv_heads=8, d_head=128)
         tp = mesh.shape["model"]
         if 8 % tp == 0:
