@@ -65,8 +65,6 @@ def main(argv=None) -> None:
                     f"available: {', '.join(all_names)}")
     rows.extend((c["name"], c["us_per_call"], c["derived"])
                 for c in fabric_cells)
-    if not fabric_only:
-        rows.extend(roofline.run())
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

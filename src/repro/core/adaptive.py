@@ -282,7 +282,8 @@ def merge_results(results: list[FabricResult], *,
         t_end=np.int32(max(int(r.t_end) for r in results)),
         drops=np.int64(sum(int(r.drops) for r in results)),
         offered=offered,
-        telemetry=merge_telemetry([r.telemetry for r in results]))
+        telemetry=merge_telemetry([r.telemetry for r in results]),
+        steps=np.int32(sum(int(r.steps) for r in results)))
 
 
 def shared_max_steps(fabric, parts: list[TrafficSpec], *,

@@ -64,6 +64,7 @@ from .network import (DEFAULT_CHUNK_SIZE, ENGINES, FabricBatchResult,
                       _tree_stream_quota, _unicast_routes)
 from .router import (AddressSpec, MulticastTable, MulticastTree,
                      RoutingTable, Topology, find_route_cycles)
+from . import tracing
 from .telemetry import Telemetry
 from .traffic import TrafficSpec
 
@@ -508,12 +509,13 @@ class Fabric:
         re-weights the tables between them, and the merged result comes
         back (per-epoch breakdown on ``self.last_report``)."""
         from .adaptive import AdaptiveRouting, run_epoched
-        if isinstance(self.routing_policy, AdaptiveRouting):
-            return run_epoched(self, spec,
-                               epochs=self.routing_policy.epochs,
-                               max_steps=max_steps,
-                               policy=self.routing_policy)
-        return self._run_single(spec, max_steps=max_steps)
+        with tracing.span("run"):
+            if isinstance(self.routing_policy, AdaptiveRouting):
+                return run_epoched(self, spec,
+                                   epochs=self.routing_policy.epochs,
+                                   max_steps=max_steps,
+                                   policy=self.routing_policy)
+            return self._run_single(spec, max_steps=max_steps)
 
     def run_epochs(self, spec: TrafficSpec, *, epochs: int,
                    max_steps: int | None = None) -> FabricResult:
@@ -703,11 +705,16 @@ class Fabric:
         # compile(spec) -> run(spec) lifecycle (and repeated runs of one
         # spec) pays the setup-time numpy (expansion, route walking,
         # prefill) once, not per call
-        memo = self._plan_memo
-        if memo is not None and memo[0] is spec and memo[1] == max_steps:
-            return memo[2]
-        plan = self._plan_impl(spec, max_steps)
-        self._plan_memo = (spec, max_steps, plan)
+        with tracing.span("plan") as sp:
+            memo = self._plan_memo
+            hit = (memo is not None and memo[0] is spec
+                   and memo[1] == max_steps)
+            if hit:
+                plan = memo[2]
+            else:
+                plan = self._plan_impl(spec, max_steps)
+                self._plan_memo = (spec, max_steps, plan)
+            sp.stat(events=plan.E, memo=int(hit))
         return plan
 
     def _unicast_tables(self):
@@ -1045,50 +1052,62 @@ class CompiledFabric:
         fab = self.fabric
         E, L = plan.E, fab.topo.n_links
         mb = int(fab.queues.max_burst)
-        if self.bucket[0] == "ring":
-            _, Lp, Np, Ep, C0, _Dp, _Cf, Rp, Kp, _chunk = self.bucket
-            init_tx_j, links_j, in_rank_j, tc_j, tv_j, ti_j = self._tables
-            out = self._fn(
-                jnp.asarray(_pad_to(plan.q_time, (Lp, 2, C0), int(_BIG))),
-                jnp.asarray(_pad_to(plan.q_dest, (Lp, 2, C0), 0)),
-                jnp.asarray(_pad_to(plan.q_inj, (Lp, 2, C0), 0)),
-                jnp.asarray(_pad_to(plan.sizes, (Lp, 2), 0)),
-                init_tx_j, links_j,
-                jnp.asarray(_pad_to(plan.route_out, (Np, Rp, Kp), -1)),
-                jnp.asarray(_pad_to(plan.route_del, (Np, Rp), 0)),
-                jnp.asarray(_pad_to(plan.route_wt, (Np, Rp, Kp), 0)),
-                in_rank_j, tc_j, tv_j, ti_j,
-                jnp.int32(plan.cap), jnp.int32(E), jnp.int32(mb),
-                jnp.int32(plan.max_steps), jnp.int32(plan.fc),
-                jnp.int32(plan.xon))
-            (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link,
-             drops, busy_ns, busy_steps, q_drops, stall_steps,
-             credit_waits) = out
-            # trim the shape-bucket padding back to the real fabric
-            log_inj, log_del, log_dest = (log_inj[:E], log_del[:E],
-                                          log_dest[:E])
-            sent, n_sw, t_link = sent[:L], n_sw[:L], t_link[:L]
-            busy_ns, busy_steps, q_drops = (busy_ns[:L], busy_steps[:L],
-                                            q_drops[:L])
-            stall_steps, credit_waits = stall_steps[:L], credit_waits[:L]
-            t_end = jnp.max(t_link)
-        else:
-            C = plan.C
-            init_tx_j, links_j, tc_j, tv_j, ti_j = self._tables
-            out = self._fn(jnp.asarray(plan.q_time).reshape(2 * L, C),
-                           jnp.asarray(plan.q_dest).reshape(2 * L, C),
-                           jnp.asarray(plan.q_inj).reshape(2 * L, C),
-                           jnp.asarray(plan.sizes),
-                           init_tx_j, links_j,
-                           jnp.asarray(plan.route_out),
-                           jnp.asarray(plan.route_del),
-                           jnp.asarray(plan.route_wt),
-                           tc_j, tv_j, ti_j,
-                           jnp.int32(plan.cap), jnp.int32(plan.fc),
-                           jnp.int32(plan.xon))
-            (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link, t_end,
-             drops, busy_ns, busy_steps, q_drops, stall_steps,
-             credit_waits) = out
+        ring = self.bucket[0] == "ring"
+        with tracing.span("marshal", instances=1) as sp:
+            if ring:
+                _, Lp, Np, Ep, C0, _Dp, _Cf, Rp, Kp, _chunk = self.bucket
+                host = (_pad_to(plan.q_time, (Lp, 2, C0), int(_BIG)),
+                        _pad_to(plan.q_dest, (Lp, 2, C0), 0),
+                        _pad_to(plan.q_inj, (Lp, 2, C0), 0),
+                        _pad_to(plan.sizes, (Lp, 2), 0),
+                        _pad_to(plan.route_out, (Np, Rp, Kp), -1),
+                        _pad_to(plan.route_del, (Np, Rp), 0),
+                        _pad_to(plan.route_wt, (Np, Rp, Kp), 0))
+                scalars = (plan.cap, E, mb, plan.max_steps, plan.fc,
+                           plan.xon)
+            else:
+                C = plan.C
+                host = (np.asarray(plan.q_time).reshape(2 * L, C),
+                        np.asarray(plan.q_dest).reshape(2 * L, C),
+                        np.asarray(plan.q_inj).reshape(2 * L, C),
+                        plan.sizes, plan.route_out, plan.route_del,
+                        plan.route_wt)
+                scalars = (plan.cap, plan.fc, plan.xon)
+            arrays = [jnp.asarray(a) for a in host]
+            # both engines take (traffic, polarity, links, replication
+            # tables, [in-edge ranks,] timing, scalars)
+            tabs = self._tables
+            operands = (*arrays[:4], *tabs[:2], *arrays[4:], *tabs[2:],
+                        *(jnp.int32(v) for v in scalars))
+            sp.stat(bytes=sum(np.asarray(a).nbytes for a in host)
+                    + 4 * len(scalars))
+        with tracing.span("dispatch", instances=1) as sp:
+            n_cached = self.cache_size()
+            out = self._fn(*operands)
+            if ring:
+                (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link,
+                 drops, busy_ns, busy_steps, q_drops, stall_steps,
+                 credit_waits, steps) = out
+                # trim the shape-bucket padding back to the real fabric
+                log_inj, log_del, log_dest = (log_inj[:E], log_del[:E],
+                                              log_dest[:E])
+                sent, n_sw, t_link = sent[:L], n_sw[:L], t_link[:L]
+                busy_ns, busy_steps, q_drops = (busy_ns[:L],
+                                                busy_steps[:L],
+                                                q_drops[:L])
+                stall_steps, credit_waits = (stall_steps[:L],
+                                             credit_waits[:L])
+                t_end = jnp.max(t_link)
+                # the engine counts whole chunks; the last may stop at
+                # the bound
+                steps = jnp.minimum(steps, plan.max_steps)
+            else:
+                (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link,
+                 t_end, drops, busy_ns, busy_steps, q_drops, stall_steps,
+                 credit_waits) = out
+                # the slot engines scan their whole static length
+                steps = np.int32(self.bucket[4])
+            sp.stat(compiled=int(self.cache_size() > n_cached))
         self.n_runs += 1
         self._warmed = True  # first real run compiles the bucket too
         return FabricResult(
@@ -1099,7 +1118,8 @@ class CompiledFabric:
             offered=plan.offered,
             telemetry=Telemetry(busy_ns=busy_ns, busy_steps=busy_steps,
                                 q_drops=q_drops, stall_steps=stall_steps,
-                                credit_waits=credit_waits))
+                                credit_waits=credit_waits),
+            steps=steps)
 
 
 # -----------------------------------------------------------------------
@@ -1133,9 +1153,10 @@ def run_batch(fabrics, specs, *, max_steps: int | None = None,
         raise ValueError(f"got {len(fabs)} fabrics for {len(specs)} "
                          f"specs; they must pair 1:1 (or pass a single "
                          f"Fabric to replicate)")
-    plans = _plan_batch(fabs, specs, max_steps)
-    return _execute_batch(fabs, plans,
-                          _resolve_devices(devices, len(plans)))
+    with tracing.span("run_batch", instances=len(specs)):
+        plans = _plan_batch(fabs, specs, max_steps)
+        return _execute_batch(fabs, plans,
+                              _resolve_devices(devices, len(plans)))
 
 
 def _plan_batch(fabs: list[Fabric], specs, max_steps: int | None):
@@ -1252,71 +1273,82 @@ def _execute_batch(fabs: list[Fabric], plans: list[_Plan],
     bucket = plans[0].bucket
     fn = _batch_engine_for(bucket, n_devices)
     L = fabs[0].topo.n_links
-    tabs = [f._get_compiled(bucket)._tables for f in fabs]
+    B = len(plans)
+    ring = bucket[0] == "ring"
+    with tracing.span("marshal", instances=B) as sp:
+        tabs = [f._get_compiled(bucket)._tables for f in fabs]
+        host = []       # every host array handed to the device
 
-    def stk(i):
-        return jnp.stack([t[i] for t in tabs])
+        def put(a):
+            host.append(a)
+            return jnp.asarray(a)
 
-    def vec(xs):
-        return jnp.asarray(np.asarray(list(xs), np.int32))
+        def stk(i):
+            return jnp.stack([t[i] for t in tabs])
 
-    if bucket[0] == "ring":
-        _, Lp, Np, _Ep, C0, _Dp, _Cf, Rp, Kp, _chunk = bucket
-        out = fn(
-            jnp.stack([jnp.asarray(_pad_to(p.q_time, (Lp, 2, C0),
-                                           int(_BIG))) for p in plans]),
-            jnp.stack([jnp.asarray(_pad_to(p.q_dest, (Lp, 2, C0), 0))
-                       for p in plans]),
-            jnp.stack([jnp.asarray(_pad_to(p.q_inj, (Lp, 2, C0), 0))
-                       for p in plans]),
-            jnp.stack([jnp.asarray(_pad_to(p.sizes, (Lp, 2), 0))
-                       for p in plans]),
-            stk(0), stk(1),
-            jnp.stack([jnp.asarray(_pad_to(p.route_out, (Np, Rp, Kp), -1))
-                       for p in plans]),
-            jnp.stack([jnp.asarray(_pad_to(p.route_del, (Np, Rp), 0))
-                       for p in plans]),
-            jnp.stack([jnp.asarray(_pad_to(p.route_wt, (Np, Rp, Kp), 0))
-                       for p in plans]),
-            stk(2), stk(3), stk(4), stk(5),
-            vec(p.cap for p in plans), vec(p.E for p in plans),
-            vec(int(f.queues.max_burst) for f in fabs),
-            # shared scalar step bound (aligned by _plan_batch) — the
-            # batched runner keeps its chunk bookkeeping unbatched
-            jnp.int32(max(p.max_steps for p in plans)),
-            vec(p.fc for p in plans), vec(p.xon for p in plans))
-        (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link, drops,
-         busy_ns, busy_steps, q_drops, stall_steps, credit_waits) = out
-        e_max = max((p.E for p in plans), default=0)
-        log_inj, log_del, log_dest = (log_inj[:, :e_max],
-                                      log_del[:, :e_max],
-                                      log_dest[:, :e_max])
-        sent, n_sw, t_link = sent[:, :L], n_sw[:, :L], t_link[:, :L]
-        busy_ns, busy_steps = busy_ns[:, :L], busy_steps[:, :L]
-        q_drops = q_drops[:, :L]
-        stall_steps, credit_waits = (stall_steps[:, :L],
-                                     credit_waits[:, :L])
-        t_end = jnp.max(t_link, axis=1)
-    else:
-        C = plans[0].C
-        out = fn(
-            jnp.stack([jnp.asarray(p.q_time).reshape(2 * L, C)
-                       for p in plans]),
-            jnp.stack([jnp.asarray(p.q_dest).reshape(2 * L, C)
-                       for p in plans]),
-            jnp.stack([jnp.asarray(p.q_inj).reshape(2 * L, C)
-                       for p in plans]),
-            jnp.stack([jnp.asarray(p.sizes) for p in plans]),
-            stk(0), stk(1),
-            jnp.stack([jnp.asarray(p.route_out) for p in plans]),
-            jnp.stack([jnp.asarray(p.route_del) for p in plans]),
-            jnp.stack([jnp.asarray(p.route_wt) for p in plans]),
-            stk(2), stk(3), stk(4),
-            vec(p.cap for p in plans), vec(p.fc for p in plans),
-            vec(p.xon for p in plans))
-        (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link, t_end,
-         drops, busy_ns, busy_steps, q_drops, stall_steps,
-         credit_waits) = out
+        def per(fn):
+            return jnp.stack([put(fn(p)) for p in plans])
+
+        def vec(xs):
+            return put(np.asarray(list(xs), np.int32))
+
+        if ring:
+            _, Lp, Np, _Ep, C0, _Dp, _Cf, Rp, Kp, _chunk = bucket
+            arrays = (
+                per(lambda p: _pad_to(p.q_time, (Lp, 2, C0), int(_BIG))),
+                per(lambda p: _pad_to(p.q_dest, (Lp, 2, C0), 0)),
+                per(lambda p: _pad_to(p.q_inj, (Lp, 2, C0), 0)),
+                per(lambda p: _pad_to(p.sizes, (Lp, 2), 0)),
+                per(lambda p: _pad_to(p.route_out, (Np, Rp, Kp), -1)),
+                per(lambda p: _pad_to(p.route_del, (Np, Rp), 0)),
+                per(lambda p: _pad_to(p.route_wt, (Np, Rp, Kp), 0)))
+            scalars = (
+                vec(p.cap for p in plans), vec(p.E for p in plans),
+                vec(int(f.queues.max_burst) for f in fabs),
+                # shared scalar step bound (aligned by _plan_batch) — the
+                # batched runner keeps its chunk bookkeeping unbatched
+                put(np.int32(max(p.max_steps for p in plans))),
+                vec(p.fc for p in plans), vec(p.xon for p in plans))
+        else:
+            C = plans[0].C
+            arrays = (
+                per(lambda p: np.asarray(p.q_time).reshape(2 * L, C)),
+                per(lambda p: np.asarray(p.q_dest).reshape(2 * L, C)),
+                per(lambda p: np.asarray(p.q_inj).reshape(2 * L, C)),
+                per(lambda p: p.sizes), per(lambda p: p.route_out),
+                per(lambda p: p.route_del), per(lambda p: p.route_wt))
+            scalars = (vec(p.cap for p in plans), vec(p.fc for p in plans),
+                       vec(p.xon for p in plans))
+        n_tabs = len(tabs[0])
+        operands = (*arrays[:4], stk(0), stk(1), *arrays[4:],
+                    *(stk(i) for i in range(2, n_tabs)), *scalars)
+        sp.stat(bytes=sum(np.asarray(a).nbytes for a in host))
+    with tracing.span("dispatch", instances=B) as sp:
+        n_cached = batch_cache_size(bucket, n_devices)
+        out = fn(*operands)
+        if ring:
+            (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link, drops,
+             busy_ns, busy_steps, q_drops, stall_steps, credit_waits,
+             steps) = out
+            e_max = max((p.E for p in plans), default=0)
+            log_inj, log_del, log_dest = (log_inj[:, :e_max],
+                                          log_del[:, :e_max],
+                                          log_dest[:, :e_max])
+            sent, n_sw, t_link = sent[:, :L], n_sw[:, :L], t_link[:, :L]
+            busy_ns, busy_steps = busy_ns[:, :L], busy_steps[:, :L]
+            q_drops = q_drops[:, :L]
+            stall_steps, credit_waits = (stall_steps[:, :L],
+                                         credit_waits[:, :L])
+            t_end = jnp.max(t_link, axis=1)
+            steps = jnp.minimum(steps, max(p.max_steps for p in plans))
+        else:
+            (log_n, log_inj, log_del, log_dest, sent, n_sw, t_link, t_end,
+             drops, busy_ns, busy_steps, q_drops, stall_steps,
+             credit_waits) = out
+            # the slot engines scan their whole static length
+            steps = np.full(B, bucket[4], np.int32)
+        sp.stat(compiled=int(batch_cache_size(bucket, n_devices)
+                             > n_cached))
     return FabricBatchResult(
         delivered=log_n,
         injected=np.asarray([p.E for p in plans], np.int64),
@@ -1326,4 +1358,5 @@ def _execute_batch(fabs: list[Fabric], plans: list[_Plan],
         offered=np.asarray([p.offered for p in plans], np.int64),
         telemetry=Telemetry(busy_ns=busy_ns, busy_steps=busy_steps,
                             q_drops=q_drops, stall_steps=stall_steps,
-                            credit_waits=credit_waits))
+                            credit_waits=credit_waits),
+        steps=steps)

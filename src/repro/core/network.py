@@ -184,6 +184,7 @@ from .protocol_sim import BIG_NS, LinkState, link_step_batch, reset_link
 from .transceiver import XcvrState
 from .router import (AddressSpec, MulticastTable, MulticastTree,
                      RoutingTable, Topology)
+from . import tracing
 from .telemetry import Telemetry
 from .traffic import TrafficSpec
 
@@ -240,6 +241,12 @@ class FabricResult(NamedTuple):
     telemetry: Telemetry | None = None  # per-link congestion counters
     #                          (accumulated as engine carry state; None
     #                          only on legacy hand-built results)
+    steps: jnp.ndarray | int = -1  # micro-transactions the engine
+    #                          executed: the ring engine's early exit
+    #                          stops at a whole chunk (or ``max_steps``),
+    #                          a batch at its slowest instance's; the
+    #                          slot engines scan their whole length
+    #                          (-1 = legacy result without the field)
 
     @property
     def traversals(self) -> int:
@@ -312,6 +319,9 @@ class FabricBatchResult(NamedTuple):
     drops: jnp.ndarray       # (B,)
     offered: np.ndarray      # (B,) static: pre-fanout events/instance
     telemetry: Telemetry     # (B,)-leading leaves
+    steps: jnp.ndarray | None = None  # (B,) micro-transactions executed:
+    #                          one count shared by the whole batch (per
+    #                          shard when the batch is sharded)
 
     @property
     def n_instances(self) -> int:
@@ -320,6 +330,15 @@ class FabricBatchResult(NamedTuple):
     def instance(self, i: int) -> FabricResult:
         """Instance ``i`` as a solo-shaped :class:`FabricResult` (log
         arrays trimmed to the instance's own expected delivery count)."""
+        with tracing.span("split", instances=1):
+            return self._instance(i)
+
+    def results(self) -> list[FabricResult]:
+        """All instances as solo-shaped results, batch order."""
+        with tracing.span("split", instances=self.n_instances):
+            return [self._instance(i) for i in range(self.n_instances)]
+
+    def _instance(self, i: int) -> FabricResult:
         e = int(self.injected[i])
         return FabricResult(
             delivered=self.delivered[i], injected=e,
@@ -329,11 +348,8 @@ class FabricBatchResult(NamedTuple):
             t_link=self.t_link[i], t_end=self.t_end[i],
             drops=self.drops[i], offered=int(self.offered[i]),
             telemetry=Telemetry(*(getattr(self.telemetry, f)[i]
-                                  for f in Telemetry._fields)))
-
-    def results(self) -> list[FabricResult]:
-        """All instances as solo-shaped results, batch order."""
-        return [self.instance(i) for i in range(self.n_instances)]
+                                  for f in Telemetry._fields)),
+            steps=-1 if self.steps is None else self.steps[i])
 
 
 def batch_throughput_mev_s(batch: FabricBatchResult) -> jnp.ndarray:
@@ -1392,241 +1408,252 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
         (:func:`_ring_run_batch`), which vmaps ``body`` ALONE so the
         chunk bookkeeping stays scalar."""
         K = route_out_j.shape[2]
-        link0 = reset_links(init_tx)
         # per-(link, side) delivery chip, both sides — the flow gate
         # inspects both heads before the FSM picks a direction.  Dummy
         # padded links point at chip 0 with empty queues: inert.
-        rx_chip_cand = jnp.stack([links_j[:, 1], links_j[:, 0]], axis=1)
+        with jax.named_scope("ring.fsm"):
+            rx_chip_cand = jnp.stack([links_j[:, 1], links_j[:, 0]], axis=1)
         si2 = jnp.arange(2)[None, :]
         li2 = lidx[:, None]
-        # pack the prefill columns once per trace: the per-step head read
-        # becomes one gather of (time, route, inj) triples
-        q0_all = jnp.stack([q0_time, q0_dest, q0_inj], axis=-1)
+        with jax.named_scope("ring.head"):
+            # pack the prefill columns once per trace: the per-step head read
+            # becomes one gather of (time, route, inj) triples
+            q0_all = jnp.stack([q0_time, q0_dest, q0_inj], axis=-1)
         didx = jnp.arange(D, dtype=jnp.int32)
         qid = jnp.arange(Q, dtype=jnp.int32)[None, :]
         sid = jnp.arange(Q * D, dtype=jnp.int32)
         ch4 = jnp.arange(4, dtype=jnp.int32)[None, :]
-        init = _RingState(
-            link=link0,
-            h0=jnp.zeros((L, 2), jnp.int32),
-            fh=jnp.zeros((L, 2, D), jnp.int32),
-            ftl=jnp.zeros((L, 2, D), jnp.int32),
-            fqs=jnp.where(jnp.arange(Q * D * Cf * 4) % 4 == 0,
-                          _BIG, 0).astype(jnp.int32),
-            n_ins=sizes,
-            sent=jnp.zeros((L, 2), jnp.int32),
-            prev_mode_l=link0.xl.mode,
-            n_sw=jnp.zeros((L,), jnp.int32),
-            log_pk=jnp.zeros((E + L, 3), jnp.int32),
-            log_n=jnp.zeros((), jnp.int32),
-            drops=jnp.zeros((), jnp.int32),
-            busy_ns=jnp.zeros((L,), jnp.int32),
-            busy_steps=jnp.zeros((L, 2), jnp.int32),
-            q_drops=jnp.zeros((L, 2), jnp.int32),
-            n_pop=jnp.zeros((L, 2), jnp.int32),
-            xoff=jnp.zeros((L, 2), jnp.int32),
-            in_stall=jnp.zeros((L, 2), jnp.int32),
-            stall_steps=jnp.zeros((L, 2), jnp.int32),
-            credit_waits=jnp.zeros((L, 2), jnp.int32),
-        )
+        with jax.named_scope("ring.init"):
+            # the initial state, the stream buffer ``fqs`` included
+            link0 = reset_links(init_tx)
+            init = _RingState(
+                link=link0,
+                h0=jnp.zeros((L, 2), jnp.int32),
+                fh=jnp.zeros((L, 2, D), jnp.int32),
+                ftl=jnp.zeros((L, 2, D), jnp.int32),
+                fqs=jnp.where(jnp.arange(Q * D * Cf * 4) % 4 == 0,
+                              _BIG, 0).astype(jnp.int32),
+                n_ins=sizes,
+                sent=jnp.zeros((L, 2), jnp.int32),
+                prev_mode_l=link0.xl.mode,
+                n_sw=jnp.zeros((L,), jnp.int32),
+                log_pk=jnp.zeros((E + L, 3), jnp.int32),
+                log_n=jnp.zeros((), jnp.int32),
+                drops=jnp.zeros((), jnp.int32),
+                busy_ns=jnp.zeros((L,), jnp.int32),
+                busy_steps=jnp.zeros((L, 2), jnp.int32),
+                q_drops=jnp.zeros((L, 2), jnp.int32),
+                n_pop=jnp.zeros((L, 2), jnp.int32),
+                xoff=jnp.zeros((L, 2), jnp.int32),
+                in_stall=jnp.zeros((L, 2), jnp.int32),
+                stall_steps=jnp.zeros((L, 2), jnp.int32),
+                credit_waits=jnp.zeros((L, 2), jnp.int32),
+            )
 
         def body(s: _RingState, step_i):
             t_now = s.link.t  # (L,)
 
-            # --- O(1) queue reads: stream heads only --------------------
-            # Every stream is sorted by (release, insertion key): the
-            # prefill by construction, each forward stream because its
-            # source link's delivery clock is monotone.  So per endpoint,
-            # "any released entry", the earliest released release and the
-            # earliest future arrival are all properties of the 1 + D
-            # heads — no O(C) slot scan.
-            p_head = jnp.take_along_axis(
-                q0_all, s.h0[:, :, None, None], axis=2)[:, :, 0]  # (L,2,3)
-            f_head = s.fqs[(sid * Cf + s.fh.reshape(-1))[:, None] * 4
-                           + ch4].reshape(L, 2, D, 4)            # (L,2,D,4)
-            p_t = p_head[..., 0]                                 # (L, 2)
-            f_t = f_head[..., 0]                                 # (L, 2, D)
-            p_rel = p_t <= t_now[:, None]
-            f_rel = f_t <= t_now[:, None, None]
-            pend_side = p_rel | jnp.any(f_rel, axis=2)           # (L, 2)
-            r_min = jnp.minimum(
-                jnp.where(p_rel, p_t, _BIG),
-                jnp.min(jnp.where(f_rel, f_t, _BIG), axis=2))
-            nxt = jnp.minimum(
-                jnp.where(p_rel, _BIG, p_t),
-                jnp.min(jnp.where(f_rel, _BIG, f_t), axis=2))    # (L, 2)
+            with jax.named_scope("ring.head"):
+                # --- O(1) queue reads: stream heads only --------------------
+                # Every stream is sorted by (release, insertion key): the
+                # prefill by construction, each forward stream because its
+                # source link's delivery clock is monotone.  So per endpoint,
+                # "any released entry", the earliest released release and the
+                # earliest future arrival are all properties of the 1 + D
+                # heads — no O(C) slot scan.
+                p_head = jnp.take_along_axis(
+                    q0_all, s.h0[:, :, None, None], axis=2)[:, :, 0]  # (L,2,3)
+                f_head = s.fqs[(sid * Cf + s.fh.reshape(-1))[:, None] * 4
+                               + ch4].reshape(L, 2, D, 4)  # (L,2,D,4)
+                p_t = p_head[..., 0]                                 # (L, 2)
+                f_t = f_head[..., 0]  # (L, 2, D)
+                p_rel = p_t <= t_now[:, None]
+                f_rel = f_t <= t_now[:, None, None]
+                pend_side = p_rel | jnp.any(f_rel, axis=2)           # (L, 2)
+                r_min = jnp.minimum(
+                    jnp.where(p_rel, p_t, _BIG),
+                    jnp.min(jnp.where(f_rel, f_t, _BIG), axis=2))
+                nxt = jnp.minimum(
+                    jnp.where(p_rel, _BIG, p_t),
+                    jnp.min(jnp.where(f_rel, _BIG, f_t), axis=2))    # (L, 2)
 
-            # --- the earliest (release, key) head, BOTH sides -----------
-            # (release, insertion_key) lexicographic minimum in two int32
-            # stages (keys are unique reference slot ids per queue, so the
-            # key argmin over release ties is exact and matches the
-            # reference argmin's lowest-slot rule).  Computed before the
-            # FSM step because the flow-control gate must inspect each
-            # head's downstream targets; the send side's values are
-            # gathered out after the FSM picks a direction — identical
-            # math to a post-step send-side-only selection.
-            fk = f_head[..., 3]                                  # (L, 2, D)
-            cand_t = jnp.concatenate(
-                [p_t[:, :, None], f_t], axis=2)                  # (L,2,1+D)
-            cand_k = jnp.concatenate(
-                [s.h0[:, :, None], fk], axis=2)
-            rel_c = cand_t <= t_now[:, None, None]
-            t_best = jnp.min(jnp.where(rel_c, cand_t, _BIG), axis=2)
-            tie = rel_c & (cand_t == t_best[..., None])
-            best = jnp.argmin(jnp.where(tie, cand_k, no_key),
-                              axis=2).astype(jnp.int32)          # (L, 2)
-            from_pre = best == 0
-            d_best = jnp.maximum(best - 1, 0)
-            # the winning forward stream's head entry IS f_head at d_best
-            # (f_head gathers AT s.fh), so no second stream gather
-            best_head = f_head[li2, si2, d_best]                 # (L, 2, 4)
-            cand_route = jnp.where(
-                from_pre, p_head[..., 1], best_head[..., 1])
-            cand_inj = jnp.where(
-                from_pre, p_head[..., 2], best_head[..., 2])
+            with jax.named_scope("ring.fsm"):
+                # --- the earliest (release, key) head, BOTH sides -----------
+                # (release, insertion_key) lexicographic minimum in two int32
+                # stages (keys are unique reference slot ids per queue, so the
+                # key argmin over release ties is exact and matches the
+                # reference argmin's lowest-slot rule).  Computed before the
+                # FSM step because the flow-control gate must inspect each
+                # head's downstream targets; the send side's values are
+                # gathered out after the FSM picks a direction — identical
+                # math to a post-step send-side-only selection.
+                fk = f_head[..., 3]  # (L, 2, D)
+                cand_t = jnp.concatenate(
+                    [p_t[:, :, None], f_t], axis=2)  # (L,2,1+D)
+                cand_k = jnp.concatenate(
+                    [s.h0[:, :, None], fk], axis=2)
+                rel_c = cand_t <= t_now[:, None, None]
+                t_best = jnp.min(jnp.where(rel_c, cand_t, _BIG), axis=2)
+                tie = rel_c & (cand_t == t_best[..., None])
+                best = jnp.argmin(jnp.where(tie, cand_k, no_key),
+                                  axis=2).astype(jnp.int32)          # (L, 2)
+                from_pre = best == 0
+                d_best = jnp.maximum(best - 1, 0)
+                # the winning forward stream's head entry IS f_head at d_best
+                # (f_head gathers AT s.fh), so no second stream gather
+                best_head = f_head[li2, si2, d_best]  # (L, 2, 4)
+                cand_route = jnp.where(
+                    from_pre, p_head[..., 1], best_head[..., 1])
+                cand_inj = jnp.where(
+                    from_pre, p_head[..., 2], best_head[..., 2])
 
-            # --- flow-control admission gate ----------------------------
-            # Identical inputs and formulas to the slot engines: the
-            # occupancy n_ins - n_pop is O(1) carry state, and the head
-            # route is exactly the slot engines' q_dest[q, amin] gather.
-            occ = s.n_ins - s.n_pop
-            blocked, xoff = _flow_gate(fc_mode, cap, xon, occ, s.xoff,
-                                       cand_route, rx_chip_cand,
-                                       route_out_j)
-            stalled = pend_side & blocked
-            stall_steps = s.stall_steps + stalled.astype(jnp.int32)
-            credit_waits = s.credit_waits + (
-                stalled & (s.in_stall == 0)).astype(jnp.int32)
+                # --- flow-control admission gate ----------------------------
+                # Identical inputs and formulas to the slot engines: the
+                # occupancy n_ins - n_pop is O(1) carry state, and the head
+                # route is exactly the slot engines' q_dest[q, amin] gather.
+                occ = s.n_ins - s.n_pop
+                blocked, xoff = _flow_gate(fc_mode, cap, xon, occ, s.xoff,
+                                           cand_route, rx_chip_cand,
+                                           route_out_j)
+                stalled = pend_side & blocked
+                stall_steps = s.stall_steps + stalled.astype(jnp.int32)
+                credit_waits = s.credit_waits + (
+                    stalled & (s.in_stall == 0)).astype(jnp.int32)
 
-            # --- conservative clock synchronization ---------------------
-            # Identical contract to the reference engine (see
-            # _slot_engine, including the per-link ``min(na + t_cycle)``
-            # insert bound and the per-side head-of-line/stall rules);
-            # head releases are exact stand-ins: a side with work pending
-            # contributes the clock (gated: excluded), and a side with
-            # none has every head unreleased, so the head minimum IS the
-            # stream minimum — the one state where arrival times behind
-            # heads would be invisible here is exactly the state the
-            # head-of-line rule makes them irrelevant in.
-            na_side = jnp.where(
-                pend_side, jnp.where(blocked, _BIG, t_now[:, None]), nxt)
-            na = jnp.min(na_side, axis=1)                        # (L,)
-            t_next_g = jnp.min(jnp.where(pend_side, _BIG, nxt), axis=1)
-            horizon = jnp.min(na)
-            t_next_eff = jnp.minimum(t_next_g,
-                                     jnp.maximum(horizon, t_now))
-            safe = r_min <= jnp.min(na + t_cycle_v)              # (L, 2)
-            pend_safe = (pend_side & safe & ~blocked).astype(jnp.int32)
+                # --- conservative clock synchronization ---------------------
+                # Identical contract to the reference engine (see
+                # _slot_engine, including the per-link ``min(na + t_cycle)``
+                # insert bound and the per-side head-of-line/stall rules);
+                # head releases are exact stand-ins: a side with work pending
+                # contributes the clock (gated: excluded), and a side with
+                # none has every head unreleased, so the head minimum IS the
+                # stream minimum — the one state where arrival times behind
+                # heads would be invisible here is exactly the state the
+                # head-of-line rule makes them irrelevant in.
+                na_side = jnp.where(
+                    pend_side, jnp.where(blocked, _BIG, t_now[:, None]), nxt)
+                na = jnp.min(na_side, axis=1)                        # (L,)
+                t_next_g = jnp.min(jnp.where(pend_side, _BIG, nxt), axis=1)
+                horizon = jnp.min(na)
+                t_next_eff = jnp.minimum(t_next_g,
+                                         jnp.maximum(horizon, t_now))
+                safe = r_min <= jnp.min(na + t_cycle_v)              # (L, 2)
+                pend_safe = (pend_side & safe & ~blocked).astype(jnp.int32)
 
-            # --- one micro-transaction on every link, batched -----------
-            link, out = link_step_batch(
-                s.link, pend_safe[:, 0], pend_safe[:, 1], t_next_eff,
-                max_burst=max_burst,
-                timing_arrays=(t_cycle_v, t_rev_v, t_idle_v))
+                # --- one micro-transaction on every link, batched -----------
+                link, out = link_step_batch(
+                    s.link, pend_safe[:, 0], pend_safe[:, 1], t_next_eff,
+                    max_burst=max_burst,
+                    timing_arrays=(t_cycle_v, t_rev_v, t_idle_v))
 
-            did = (out.tx_l + out.tx_r) > 0                      # (L,) bool
-            did32 = did.astype(jnp.int32)
-            # telemetry: backlog indicator + transmission-gated clock
-            # delta — head properties only, so the O(1)-per-step contract
-            # holds; bit-exact with the slot engines' (pend > 0) counter
-            busy_steps = s.busy_steps + pend_side.astype(jnp.int32)
-            busy_ns = s.busy_ns + jnp.where(did, link.t - t_now, 0)
-            send_side = jnp.where(out.tx_l == 1, 0, 1)           # (L,)
+                did = (out.tx_l + out.tx_r) > 0  # (L,) bool
+                did32 = did.astype(jnp.int32)
+                send_side = jnp.where(out.tx_l == 1, 0, 1)           # (L,)
+            with jax.named_scope("ring.telemetry"):
+                # telemetry: backlog indicator + transmission-gated clock
+                # delta — head properties only, so the O(1)-per-step contract
+                # holds; bit-exact with the slot engines' (pend > 0) counter
+                busy_steps = s.busy_steps + pend_side.astype(jnp.int32)
+                busy_ns = s.busy_ns + jnp.where(did, link.t - t_now, 0)
 
-            # --- pop the send side's head, return its credit ------------
-            fp_s = from_pre[lidx, send_side]                     # (L,)
-            db_s = d_best[lidx, send_side]
-            ev_route = cand_route[lidx, send_side]
-            ev_inj = cand_inj[lidx, send_side]
-            # single update per link row -> dense one-hot adds, not
-            # scatters (XLA lowers small scatters to a per-row loop; under
-            # vmap that loop serializes across the batch too)
-            oh_side = si2 == send_side[:, None]                  # (L, 2)
-            h0 = s.h0 + jnp.where(
-                oh_side, (did & fp_s).astype(jnp.int32)[:, None], 0)
-            oh_d = oh_side[:, :, None] & (didx == db_s[:, None, None])
-            fh = s.fh + jnp.where(
-                oh_d, (did & ~fp_s).astype(jnp.int32)[:, None, None], 0)
-            sent = s.sent + jnp.where(oh_side, did32[:, None], 0)
-            n_pop = s.n_pop + jnp.where(oh_side, did32[:, None], 0)
+            with jax.named_scope("ring.head"):
+                # --- pop the send side's head, return its credit ------------
+                fp_s = from_pre[lidx, send_side]                     # (L,)
+                db_s = d_best[lidx, send_side]
+                ev_route = cand_route[lidx, send_side]
+                ev_inj = cand_inj[lidx, send_side]
+                # single update per link row -> dense one-hot adds, not
+                # scatters (XLA lowers small scatters to a per-row loop; under
+                # vmap that loop serializes across the batch too)
+                oh_side = si2 == send_side[:, None]                  # (L, 2)
+                h0 = s.h0 + jnp.where(
+                    oh_side, (did & fp_s).astype(jnp.int32)[:, None], 0)
+                oh_d = oh_side[:, :, None] & (didx == db_s[:, None, None])
+                fh = s.fh + jnp.where(
+                    oh_d, (did & ~fp_s).astype(jnp.int32)[:, None, None], 0)
+                sent = s.sent + jnp.where(oh_side, did32[:, None], 0)
+                n_pop = s.n_pop + jnp.where(oh_side, did32[:, None], 0)
 
-            # --- deliver and/or replicate -------------------------------
-            # The replication-table row of (rx_chip, route) decides both:
-            # a multicast branch node can deliver locally AND spawn up to
-            # K child copies from this one pop.
-            rx_side = jnp.where(out.tx_l == 1, 1, 0)
-            rx_chip = links_j[lidx, rx_side]
-            deliver = did & (route_del_j[rx_chip, ev_route] > 0)
+            with jax.named_scope("ring.log"):
+                # --- deliver and/or replicate -------------------------------
+                # The replication-table row of (rx_chip, route) decides both:
+                # a multicast branch node can deliver locally AND spawn up to
+                # K child copies from this one pop.
+                rx_side = jnp.where(out.tx_l == 1, 1, 0)
+                rx_chip = links_j[lidx, rx_side]
+                deliver = did & (route_del_j[rx_chip, ev_route] > 0)
 
-            # Delivery slots are consecutive from log_n (the same
-            # log_n + cumsum slot rule as _log_deliveries), so instead of
-            # three scatters the step compacts the delivering links to
-            # the front — inv[p] is the (p+1)-th delivering link id,
-            # counted densely — and writes ONE (L, 3) block with
-            # dynamic_update_slice.  Rows at or past this step's delivery
-            # count nd are forced to zero: the next step's block starts
-            # exactly where this one's valid rows end, so overhang rows
-            # are always overwritten by later valid rows, and the final
-            # overhang leaves the same zeros an untouched buffer holds.
-            # The buffer's L-row slack keeps the slice start (<= E) from
-            # ever clamping.
-            d32l = deliver.astype(jnp.int32)
-            nd = jnp.sum(d32l)
-            csum = jnp.cumsum(d32l)
-            inv = jnp.minimum(jnp.sum(
-                (csum[None, :] <= lidx[:, None]).astype(jnp.int32),
-                axis=1), L - 1)                                  # (L,)
-            blk = jnp.where(
-                (lidx < nd)[:, None],
-                jnp.stack([ev_inj[inv], link.t[inv], rx_chip[inv]],
-                          axis=-1), 0)                           # (L, 3)
-            log_pk = jax.lax.dynamic_update_slice(
-                s.log_pk, blk, (s.log_n, jnp.int32(0)))
-            log_n = s.log_n + nd
+                # Delivery slots are consecutive from log_n (the same
+                # log_n + cumsum slot rule as _log_deliveries), so instead of
+                # three scatters the step compacts the delivering links to
+                # the front — inv[p] is the (p+1)-th delivering link id,
+                # counted densely — and writes ONE (L, 3) block with
+                # dynamic_update_slice.  Rows at or past this step's delivery
+                # count nd are forced to zero: the next step's block starts
+                # exactly where this one's valid rows end, so overhang rows
+                # are always overwritten by later valid rows, and the final
+                # overhang leaves the same zeros an untouched buffer holds.
+                # The buffer's L-row slack keeps the slice start (<= E) from
+                # ever clamping.
+                d32l = deliver.astype(jnp.int32)
+                nd = jnp.sum(d32l)
+                csum = jnp.cumsum(d32l)
+                inv = jnp.minimum(jnp.sum(
+                    (csum[None, :] <= lidx[:, None]).astype(jnp.int32),
+                    axis=1), L - 1)                                  # (L,)
+                blk = jnp.where(
+                    (lidx < nd)[:, None],
+                    jnp.stack([ev_inj[inv], link.t[inv], rx_chip[inv]],
+                              axis=-1), 0)                           # (L, 3)
+                log_pk = jax.lax.dynamic_update_slice(
+                    s.log_pk, blk, (s.log_n, jnp.int32(0)))
+                log_n = s.log_n + nd
 
-            # --- forward append: tails of the delivering link's streams -
-            # All K copies of one pop land at the SAME chip on K distinct
-            # out-queues, so every active (queue, in-edge) target below
-            # is unique and the multi-scatter is race-free.
-            fwd_f, fqk_f, wt_f = _replicate(route_out_j, route_wt_j,
-                                            rx_chip, ev_route, did)
-            n_ins_f = s.n_ins.reshape(-1)
-            # ``key`` is the reference slot id: the pop tie-break key.
-            # Only drop mode discards at append time; the stall modes
-            # are lossless and the stream quotas already bound storage.
-            app_cap = jnp.where(fc_mode == 0, cap, jnp.int32(_BIG))
-            fq_g, key, app, dropped = _forward_slots(
-                fwd_f, fqk_f, n_ins_f, app_cap, Q)
-            d_ins = jnp.repeat(in_rank_j[lidx, rx_side], K)      # (L·K,)
-            stream = fq_g * D + d_ins          # flat stream id
-            stream_s = jnp.where(app, stream, Q * D)
-            tail = s.ftl.reshape(-1)[stream]                     # (L·K,)
-            # ONE packed append per step: all four channels of one entry
-            # travel in a single (L·K, 4) scatter row
-            upd = jnp.stack(
-                [jnp.repeat(link.t, K), jnp.repeat(ev_route, K),
-                 jnp.repeat(ev_inj, K), key], axis=-1)           # (L·K, 4)
-            fqs = s.fqs.at[(stream_s * Cf + tail)[:, None] * 4 + ch4].set(
-                upd, mode="drop")
-            # counter bumps as dense one-hot sums over the tiny (Q,) and
-            # (D,) index spaces — masked rows contribute zero everywhere
-            eq_q = fq_g[:, None] == qid                          # (L·K, Q)
-            app_q = (eq_q & app[:, None]).astype(jnp.int32)
-            n_ins = (n_ins_f + jnp.sum(app_q, axis=0)).reshape(L, 2)
-            eq_d = (d_ins[:, None] == didx[None, :]).astype(jnp.int32)
-            ftl = (s.ftl.reshape(Q, D) + jnp.einsum(
-                'rq,rd->qd', app_q, eq_d)).reshape(L, 2, D)
-            drop_wt = jnp.where(dropped, wt_f, 0)
-            drops = s.drops + jnp.sum(drop_wt)
-            # telemetry: charge each weighted drop to its target queue
-            q_drops = (s.q_drops.reshape(-1) + jnp.sum(
-                eq_q.astype(jnp.int32) * drop_wt[:, None], axis=0)
-                ).reshape(L, 2)
+            with jax.named_scope("ring.forward"):
+                # --- forward append: tails of the delivering link's streams -
+                # All K copies of one pop land at the SAME chip on K distinct
+                # out-queues, so every active (queue, in-edge) target below
+                # is unique and the multi-scatter is race-free.
+                fwd_f, fqk_f, wt_f = _replicate(route_out_j, route_wt_j,
+                                                rx_chip, ev_route, did)
+                n_ins_f = s.n_ins.reshape(-1)
+                # ``key`` is the reference slot id: the pop tie-break key.
+                # Only drop mode discards at append time; the stall modes
+                # are lossless and the stream quotas already bound storage.
+                app_cap = jnp.where(fc_mode == 0, cap, jnp.int32(_BIG))
+                fq_g, key, app, dropped = _forward_slots(
+                    fwd_f, fqk_f, n_ins_f, app_cap, Q)
+                d_ins = jnp.repeat(in_rank_j[lidx, rx_side], K)      # (L·K,)
+                stream = fq_g * D + d_ins          # flat stream id
+                stream_s = jnp.where(app, stream, Q * D)
+                tail = s.ftl.reshape(-1)[stream]                     # (L·K,)
+                # ONE packed append per step: all four channels of one entry
+                # travel in a single (L·K, 4) scatter row
+                upd = jnp.stack(
+                    [jnp.repeat(link.t, K), jnp.repeat(ev_route, K),
+                     jnp.repeat(ev_inj, K), key], axis=-1)           # (L·K, 4)
+                fqs = s.fqs.at[(stream_s * Cf + tail)[:, None] * 4 + ch4].set(
+                    upd, mode="drop")
+                # counter bumps as dense one-hot sums over the tiny (Q,) and
+                # (D,) index spaces — masked rows contribute zero everywhere
+                eq_q = fq_g[:, None] == qid                          # (L·K, Q)
+                app_q = (eq_q & app[:, None]).astype(jnp.int32)
+                n_ins = (n_ins_f + jnp.sum(app_q, axis=0)).reshape(L, 2)
+                eq_d = (d_ins[:, None] == didx[None, :]).astype(jnp.int32)
+                ftl = (s.ftl.reshape(Q, D) + jnp.einsum(
+                    'rq,rd->qd', app_q, eq_d)).reshape(L, 2, D)
+                drop_wt = jnp.where(dropped, wt_f, 0)
+                drops = s.drops + jnp.sum(drop_wt)
+            with jax.named_scope("ring.telemetry"):
+                # telemetry: charge each weighted drop to its target queue
+                q_drops = (s.q_drops.reshape(-1) + jnp.sum(
+                    eq_q.astype(jnp.int32) * drop_wt[:, None], axis=0)
+                    ).reshape(L, 2)
 
-            # --- switch counting (reset step excluded) ------------------
-            n_sw = s.n_sw + jnp.where(
-                step_i > 0,
-                (link.xl.mode != s.prev_mode_l).astype(jnp.int32), 0)
+                # --- switch counting (reset step excluded) ------------------
+                n_sw = s.n_sw + jnp.where(
+                    step_i > 0,
+                    (link.xl.mode != s.prev_mode_l).astype(jnp.int32), 0)
 
             ns = _RingState(
                 link=link, h0=h0, fh=fh, ftl=ftl,
@@ -1672,13 +1699,17 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
             st, base = carry
             return (st.log_n + st.drops < real_e) & (base < max_steps)
 
-        final, _ = jax.lax.while_loop(cond, chunk_body,
-                                      (init, jnp.int32(0)))
+        final, base = jax.lax.while_loop(cond, chunk_body,
+                                         (init, jnp.int32(0)))
+        # ``base``: the steps of the chunks run, in whole chunks; the
+        # caller clamps it to ``max_steps`` (the last chunk's clamp),
+        # outside the program, whose buffer layout this output would
+        # otherwise move
         return (final.log_n, final.log_pk[:E, 0], final.log_pk[:E, 1],
                 final.log_pk[:E, 2],
                 final.sent, final.n_sw, final.link.t, final.drops,
                 final.busy_ns, final.busy_steps, final.q_drops,
-                final.stall_steps, final.credit_waits)
+                final.stall_steps, final.credit_waits, base)
 
     run._start = start   # the batched runner reuses (init, body)
     return run
@@ -1741,13 +1772,17 @@ def _ring_run_batch(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
             return (jnp.any(st.log_n + st.drops < real_e)
                     & (base < max_steps))
 
-        final, _ = jax.lax.while_loop(cond, chunk_body,
-                                      (init, jnp.int32(0)))
+        final, base = jax.lax.while_loop(cond, chunk_body,
+                                         (init, jnp.int32(0)))
+        # one count of chunk steps for the whole batch (clamped by the
+        # caller, as solo), carried by every instance: the instance axis
+        # keeps it shardable with the other outputs
+        steps = jnp.zeros_like(real_e) + base
         return (final.log_n, final.log_pk[:, :E, 0],
                 final.log_pk[:, :E, 1], final.log_pk[:, :E, 2],
                 final.sent, final.n_sw, final.link.t, final.drops,
                 final.busy_ns, final.busy_steps, final.q_drops,
-                final.stall_steps, final.credit_waits)
+                final.stall_steps, final.credit_waits, steps)
 
     return run
 

@@ -1,1 +1,1 @@
-"""Entry points: mesh construction, dry-run, train, serve."""
+"""Entry points: mesh construction, train, serve."""

@@ -1,6 +1,6 @@
-"""Production mesh construction (a FUNCTION — importing this module never
-touches jax device state; the 512-device dry-run forces the host platform
-device count before first jax init, see dryrun.py)."""
+"""Mesh construction (functions — importing this module never touches jax
+device state, so a caller can still force the host platform's device
+count before JAX starts)."""
 
 from __future__ import annotations
 
