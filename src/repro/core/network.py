@@ -1352,7 +1352,11 @@ class _RingState(NamedTuple):
     #                           5-D array the head gather and the tail
     #                           scatter asked for different layouts and
     #                           XLA on the TPU copied the whole buffer
-    #                           between them on every step.
+    #                           between them on every step.  The
+    #                           batched runner keeps B instances' buffers
+    #                           as ONE flat (B·N,) vector, not (B, N), for
+    #                           the same reason (see _ring_run_batch); its
+    #                           vmapped state holds None here.
     n_ins: jnp.ndarray        # (L, 2) entries ever inserted (capacity/key)
     sent: jnp.ndarray         # (L, 2)
     prev_mode_l: jnp.ndarray  # (L,)
@@ -1423,6 +1427,7 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
         qid = jnp.arange(Q, dtype=jnp.int32)[None, :]
         sid = jnp.arange(Q * D, dtype=jnp.int32)
         ch4 = jnp.arange(4, dtype=jnp.int32)[None, :]
+        N = Q * D * Cf * 4   # stream-buffer entries of one instance
         with jax.named_scope("ring.init"):
             # the initial state, the stream buffer ``fqs`` included
             link0 = reset_links(init_tx)
@@ -1431,7 +1436,7 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                 h0=jnp.zeros((L, 2), jnp.int32),
                 fh=jnp.zeros((L, 2, D), jnp.int32),
                 ftl=jnp.zeros((L, 2, D), jnp.int32),
-                fqs=jnp.where(jnp.arange(Q * D * Cf * 4) % 4 == 0,
+                fqs=jnp.where(jnp.arange(N) % 4 == 0,
                               _BIG, 0).astype(jnp.int32),
                 n_ins=sizes,
                 sent=jnp.zeros((L, 2), jnp.int32),
@@ -1450,7 +1455,15 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                 credit_waits=jnp.zeros((L, 2), jnp.int32),
             )
 
-        def body(s: _RingState, step_i):
+        def body(s: _RingState, step_i, shared=None):
+            """One micro-transaction: ``(next state, None)``.
+
+            With ``shared = (fqs, off, drop_at)`` (the batched runner)
+            the stream buffer is not ``s.fqs`` but this instance's
+            ``N`` entries of ``fqs`` from ``off`` on: the head gather
+            reads there, and the tail append is returned as
+            ``(indices, entries)`` for the caller to scatter, in place
+            of the ``None``, with ``fqs=None`` in the next state."""
             t_now = s.link.t  # (L,)
 
             with jax.named_scope("ring.head"):
@@ -1463,8 +1476,10 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                 # heads — no O(C) slot scan.
                 p_head = jnp.take_along_axis(
                     q0_all, s.h0[:, :, None, None], axis=2)[:, :, 0]  # (L,2,3)
-                f_head = s.fqs[(sid * Cf + s.fh.reshape(-1))[:, None] * 4
-                               + ch4].reshape(L, 2, D, 4)  # (L,2,D,4)
+                f_at = (sid * Cf + s.fh.reshape(-1))[:, None] * 4 + ch4
+                f_head = (s.fqs[f_at] if shared is None else
+                          shared[0][f_at + shared[1]]
+                          ).reshape(L, 2, D, 4)  # (L,2,D,4)
                 p_t = p_head[..., 0]                                 # (L, 2)
                 f_t = f_head[..., 0]  # (L, 2, D)
                 p_rel = p_t <= t_now[:, None]
@@ -1632,8 +1647,17 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                 upd = jnp.stack(
                     [jnp.repeat(link.t, K), jnp.repeat(ev_route, K),
                      jnp.repeat(ev_inj, K), key], axis=-1)           # (L·K, 4)
-                fqs = s.fqs.at[(stream_s * Cf + tail)[:, None] * 4 + ch4].set(
-                    upd, mode="drop")
+                a_at = (stream_s * Cf + tail)[:, None] * 4 + ch4
+                if shared is None:
+                    fqs = s.fqs.at[a_at].set(upd, mode="drop")
+                else:
+                    # the rows the solo scatter drops (unappended rows
+                    # and any index past this instance's N entries) go
+                    # to ``drop_at``, past every instance's range, never
+                    # into the next instance's streams
+                    fqs = None
+                    append = (jnp.where(a_at < N, a_at + shared[1],
+                                        shared[2]), upd)
                 # counter bumps as dense one-hot sums over the tiny (Q,) and
                 # (D,) index spaces — masked rows contribute zero everywhere
                 eq_q = fq_g[:, None] == qid                          # (L·K, Q)
@@ -1664,7 +1688,7 @@ def _ring_run(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                 n_pop=n_pop, xoff=xoff,
                 in_stall=stalled.astype(jnp.int32),
                 stall_steps=stall_steps, credit_waits=credit_waits)
-            return ns, None
+            return ns, (None if shared is None else append)
 
         return init, body
 
@@ -1734,6 +1758,18 @@ def _ring_run_batch(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
     Bit-exactness per instance is asserted by the batch tests and the
     CI batch gate.
 
+    The stream buffer ``fqs`` is not part of the vmapped state: the
+    loop carries all B instances' buffers as ONE flat (B·N,) vector,
+    instance b owning entries ``[b·N, (b+1)·N)``.  The vmapped ``body``
+    gathers its stream heads from that vector at its own offset and
+    returns its tail append, which the runner scatters for all B
+    instances at once; rows the solo scatter would drop go to index
+    B·N, past every instance.  Carried as a batched (B, N) array, the
+    buffer sat in the head gather's tiled layout while the scatter
+    wanted it flat, and XLA on the TPU re-laid all B buffers between
+    the two on every step, instance by instance: a batched step of 32
+    cost as much as ~63 solo steps.
+
     Signature matches the solo runner with every operand carrying a
     leading ``(B,)`` instance axis — including the dynamic scalars
     (``cap``/``real_e``/``max_burst``/``fc_mode``/``xon`` become (B,)
@@ -1753,27 +1789,40 @@ def _ring_run_batch(L: int, E: int, C0: int, D: int, Cf: int, chunk: int):
                xon)
 
         init = jax.vmap(lambda *o: start(*o)[0])(*ops)
+        B, N = init.fqs.shape
+        # instance b's streams are entries [b·N, (b+1)·N) of one flat
+        # buffer; B·N is past them all, where dropped appends go
+        fqs0 = init.fqs.reshape(B * N)
+        off = jnp.arange(B, dtype=jnp.int32) * N
+        drop_at = jnp.int32(B * N)
 
-        def body_of(ops_i, s, step_i):
-            return start(*ops_i)[1](s, step_i)[0]
+        def body_of(ops_i, s, step_i, fqs, off_i):
+            return start(*ops_i)[1](s, step_i, (fqs, off_i, drop_at))
 
-        vbody = jax.vmap(body_of, in_axes=(0, 0, None))
+        vbody = jax.vmap(body_of, in_axes=(0, 0, None, None, 0))
+
+        def step(i, carry):
+            s, fqs = carry
+            s2, (at, upd) = vbody(ops, s, i, fqs, off)
+            with jax.named_scope("ring.forward"):
+                fqs = fqs.at[at].set(upd, mode="drop")
+            return s2, fqs
 
         def chunk_body(carry):
-            st, base = carry
+            st, fqs, base = carry
             this_chunk = jnp.minimum(jnp.int32(chunk), max_steps - base)
-            st2 = jax.lax.fori_loop(
+            st2, fqs2 = jax.lax.fori_loop(
                 jnp.int32(0), this_chunk,
-                lambda i, s: vbody(ops, s, base + i), st)
-            return st2, base + jnp.int32(chunk)
+                lambda i, c: step(base + i, c), (st, fqs))
+            return st2, fqs2, base + jnp.int32(chunk)
 
         def cond(carry):
-            st, base = carry
+            st, _, base = carry
             return (jnp.any(st.log_n + st.drops < real_e)
                     & (base < max_steps))
 
-        final, base = jax.lax.while_loop(cond, chunk_body,
-                                         (init, jnp.int32(0)))
+        final, _, base = jax.lax.while_loop(
+            cond, chunk_body, (init._replace(fqs=None), fqs0, jnp.int32(0)))
         # one count of chunk steps for the whole batch (clamped by the
         # caller, as solo), carried by every instance: the instance axis
         # keeps it shardable with the other outputs

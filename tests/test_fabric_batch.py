@@ -44,7 +44,7 @@ from repro.core.fabric import (EngineSpec, Fabric, MulticastPolicy,
 from repro.core.fabric import run_batch as run_batch_fn
 from repro.core.link import PAPER_TIMING, SERIAL_LVDS_TIMING, per_link_timing
 from repro.core.router import (AddressSpec, MulticastTable, RoutingTable,
-                               find_route_cycles, ring_topology)
+                               Topology, find_route_cycles, ring_topology)
 from tests._subproc import run_with_devices
 
 assert_bit_exact = net.assert_results_equal
@@ -81,8 +81,73 @@ def _mcast_spec(addr, n=24, seed=0):
                           dest=jax.numpy.asarray(dest[order], ji))
 
 
+def _torus(n=4):
+    """An n x n torus: 2·n·n links, every chip of degree 4, so with
+    n = 4 the links and the in-edge ranks fill the ring engine's
+    bucket (32 links, 4 streams per endpoint) with no padding."""
+    links = [(r * n + c, r * n + (c + 1) % n)
+             for r in range(n) for c in range(n)]
+    links += [(r * n + c, ((r + 1) % n) * n + c)
+              for r in range(n) for c in range(n)]
+    return Topology(n * n, np.asarray(links, np.int32), name=f"torus{n}")
+
+
+def _pairs(pairs, gap=40):
+    """One event per ``(src, dest)`` pair, ``gap`` ns apart."""
+    ji = jax.numpy.int32
+    src, dest = np.asarray(pairs, np.int64).T
+    return tr.TrafficSpec(src=jax.numpy.asarray(src, ji),
+                          t=jax.numpy.asarray(np.arange(len(src)) * gap, ji),
+                          dest=jax.numpy.asarray(dest, ji))
+
+
+def _drops_mid_batch():
+    """Drop mode with a small capacity on the middle instances only:
+    their appends are dropped between two lossless instances."""
+    topo = ring_topology(8)
+    lossy = QueuePolicy(capacity=36, flow="drop")
+    fabs = [Fabric(topo)] + [Fabric(topo, queues=lossy)] * 3 + [Fabric(topo)]
+    specs = [_hot(k, 8, 32) for k in range(5)]
+    return fabs, specs, [False, True, True, True, False]
+
+
+def _top_stream_last_instance():
+    """The last instance appends to the top queue (link 31, side 1) on
+    its highest reachable in-edge stream (rank 2: 7 -> 3 -> 15), the
+    stream entries right below the end of the batch's buffer."""
+    topo = _torus()
+    fabs = [Fabric(topo)] * 3
+    top = [(7, 15), (2, 15), (0, 15)] * 12 + [(5, 10), (12, 1)] * 4
+    specs = [_spec(1, 16, 8), _spec(2, 16, 8), _pairs(top)]
+    return fabs, specs, [False, False, False]
+
+
 class TestRunBatchBitExact:
     """The headline contract: instance i of a batch == solo run i."""
+
+    @pytest.mark.parametrize("case", [_drops_mid_batch,
+                                      _top_stream_last_instance],
+                             ids=lambda f: f.__name__.lstrip("_"))
+    def test_stream_buffer_offset_edges(self, case):
+        """The batched ring engine keeps every instance's streams in
+        one flat buffer, instance b at offset b·N: appends dropped in
+        the middle of the batch, and the last instance's top stream,
+        stay inside their own instance (bit-exact with solo runs)."""
+        fabs, specs, drops = case()
+        batch = run_batch_fn(fabs, specs)
+        for i, (f, s) in enumerate(zip(fabs, specs)):
+            r = batch.instance(i)
+            assert (int(r.drops) > 0) == drops[i], (i, int(r.drops))
+            assert_bit_exact(f.run(s), r, f"{case.__name__}/{i}")
+        if case is _top_stream_last_instance:
+            f, s = fabs[-1], specs[-1]
+            L, D = f.topo.n_links, f._D
+            quota = net._stream_quota(f.routing_table, f.topo.links,
+                                      f._in_rank, np.asarray(s.src),
+                                      np.asarray(s.dest), L, D)
+            assert quota[2 * L - 1, D - 2] > 0
+            bucket = f.compile(s).bucket
+            assert (bucket[1], bucket[5]) == (L, D)   # no padding
 
     @pytest.mark.parametrize("engine", sorted(net.ENGINES))
     def test_batch_matches_solo_every_engine(self, engine):

@@ -172,6 +172,38 @@ def test_ring_engine_step_copies_no_buffer(sds):
                 assert size < Ep, (name, line[:160])
 
 
+def test_ring_engine_batch_step_keeps_one_buffer_layout(sds):
+    """The batched ring engine at the batch cell's bucket (ring-16, 32
+    instances of 64 Poisson events per chip, chunk 128): no step of its
+    loops copies, slices, updates or reshapes an array holding one
+    instance's stream buffer or more.  Carried as a (B, N) array the
+    buffer was re-laid between the head gather's tiled layout and the
+    tail scatter's flat one, all B instances, on every step."""
+    B = 32
+    fab = Fabric(ring_topology(RING), engine=EngineSpec("ring",
+                                                        chunk_size=128))
+    spec = tr.poisson(jax.random.PRNGKey(0), RING, EVENTS_PER_CHIP)
+    _, Lp, Np, Ep, C0, Dp, Cf, Rp, Kp, chunk = fab._plan(spec, None).bucket
+    run = net._ring_engine_batch(Lp, Ep, C0, Dp, Cf, chunk)
+    shapes = [(Lp, 2, C0)] * 3 + [(Lp, 2), (Lp,), (Lp, 2), (Np, Rp, Kp),
+              (Np, Rp), (Np, Rp, Kp), (Lp, 2), (Lp,), (Lp,), (Lp,)]
+    scalars = [(B,)] * 3 + [()] + [(B,)] * 2    # max_steps is shared
+    hlo = run.lower(*[sds((B, *s)) for s in shapes],
+                    *[sds(s) for s in scalars]).compile().as_text()
+    n_buf = 2 * Lp * Dp * Cf * 4     # one instance's stream entries
+    bodies = _while_bodies(hlo)
+    assert bodies
+    for name, lines in bodies.items():
+        for line in lines:
+            op = re.match(r"\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([\d,]*)\]\S* "
+                          r"(copy|dynamic-slice|dynamic-update-slice|"
+                          r"reshape)\(", line)
+            if op:
+                size = math.prod(int(d) for d in op.group(1).split(",")
+                                 if d)
+                assert size < n_buf, (name, line[:160])
+
+
 def test_aer_encode_decode(sds):
     nb, block, budget = 16, ops.DEFAULT_BLOCK, ops.DEFAULT_BUDGET
     _compile(lambda x, t: aer_encode_pallas(x, t, budget, interpret=False),
